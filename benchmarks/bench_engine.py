@@ -41,6 +41,7 @@ from repro.core.perf_model import FPGACostModel, Format, Primitive, \
     TPUCostModel
 from repro.core.profiler import block_density
 from repro.data import graphs as graph_data
+from repro.jax_cache import configure_compile_cache
 from repro.models import gnn as gnn_models
 
 _OUT = pathlib.Path(__file__).resolve().parents[1] / "BENCH_engine.json"
@@ -386,6 +387,7 @@ def run(fast: bool = True, *, smoke: bool = False,
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="CI mode: one model/dataset, no BENCH_engine.json "

@@ -91,6 +91,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import emit, geomean
+from repro.jax_cache import configure_compile_cache
 from repro.serving.graph_engine import GraphServeEngine, random_requests
 from repro.serving.scheduler import ContinuousGraphServer
 
@@ -1064,6 +1065,7 @@ def run(fast: bool = True, *, smoke: bool = False,
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="CI mode: gcn only, bitwise parity check, loose "

@@ -8,6 +8,7 @@ post-overlap.  Paper: 6.8% average, hidden by scheduling."""
 from __future__ import annotations
 
 from repro import hw
+from repro.jax_cache import configure_compile_cache
 from repro.models import gnn
 
 from benchmarks.common import emit
@@ -34,4 +35,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

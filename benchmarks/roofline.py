@@ -8,6 +8,7 @@ import json
 import os
 
 from benchmarks.common import emit
+from repro.jax_cache import configure_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "results", "dryrun")
@@ -40,4 +41,5 @@ def run(results_dir: str = RESULTS) -> list:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -17,6 +17,7 @@ import traceback
 from benchmarks import (bench_engine, bench_serving, fig13_runtime_overhead,
                         roofline, table4_perf_model, table7_k2p,
                         table8_pruning, table9_compiler, table10_accelerators)
+from repro.jax_cache import configure_compile_cache
 
 SUITES = {
     "engine": lambda full: bench_engine.run(fast=not full),
@@ -52,4 +53,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

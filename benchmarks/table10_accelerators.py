@@ -7,6 +7,7 @@ is the RATIO structure: Dynasparse beats both despite lower peak TFLOPS."""
 from __future__ import annotations
 
 from repro import hw
+from repro.jax_cache import configure_compile_cache
 from repro.models import gnn
 
 from benchmarks.common import emit, geomean
@@ -37,4 +38,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -11,6 +11,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core.perf_model import FPGACostModel, Primitive
+from repro.jax_cache import configure_compile_cache
 from repro.kernels import ops
 
 from benchmarks.common import emit, timeit
@@ -49,4 +50,5 @@ def run(fast: bool = True) -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -8,6 +8,7 @@ speedups and the geomean (paper: 2.13x and 1.59x).
 from __future__ import annotations
 
 from repro import hw
+from repro.jax_cache import configure_compile_cache
 from repro.models import gnn
 
 from benchmarks.common import emit, geomean
@@ -35,4 +36,5 @@ def run(models=MODELS, datasets=DATASETS) -> dict:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

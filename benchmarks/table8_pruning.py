@@ -4,6 +4,7 @@ strategy's advantage must GROW with sparsity (S1/S2 cannot exploit it)."""
 from __future__ import annotations
 
 from repro import hw
+from repro.jax_cache import configure_compile_cache
 from repro.models import gnn
 
 from benchmarks.common import emit, geomean
@@ -38,4 +39,5 @@ def run(models=MODELS, datasets=DATASETS) -> dict:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -10,6 +10,7 @@ import numpy as np
 from repro.core import compiler
 from repro.core.compiler import GNNModelSpec, GraphMeta
 from repro.data import graphs
+from repro.jax_cache import configure_compile_cache
 from repro.models.gnn import make_model_spec
 
 from benchmarks.common import emit
@@ -31,4 +32,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro import hw
 from repro.core import runtime
+from repro.jax_cache import configure_compile_cache
 from repro.models import gnn
 
 
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -11,8 +11,10 @@ import jax.numpy as jnp
 
 from repro.core.dynasparse import dynasparse_matmul
 from repro.core.perf_model import FPGACostModel, Primitive
+from repro.jax_cache import configure_compile_cache
 from repro.kernels import ops
 
+configure_compile_cache()
 rng = np.random.default_rng(0)
 
 # a block-structured sparse matrix (dense block + sparse band + dead zone)

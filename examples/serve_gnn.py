@@ -28,6 +28,7 @@ import time
 import numpy as np
 
 from repro.data.sampling import powerlaw_host_graph
+from repro.jax_cache import configure_compile_cache
 from repro.serving.graph_engine import GraphServeEngine, random_requests
 from repro.serving.minibatch import FeatureStore, MiniBatchServeEngine
 from repro.serving.scheduler import ContinuousGraphServer
@@ -191,4 +192,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

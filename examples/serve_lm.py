@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import smoke_config
 from repro.core.dynasparse import dynasparse_matmul
 from repro.core.perf_model import TPUCostModel
+from repro.jax_cache import configure_compile_cache
 from repro.launch.serve import prune_ffn
 from repro.models import model_zoo
 from repro.serving.engine import Request, ServeEngine
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

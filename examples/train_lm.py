@@ -11,6 +11,7 @@ step, async checkpoints, straggler accounting).
 import argparse
 import sys
 
+from repro.jax_cache import configure_compile_cache
 from repro.launch import train as train_launch
 
 
@@ -33,4 +34,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

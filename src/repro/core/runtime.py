@@ -38,7 +38,6 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import analyzer, profiler, scheduler
@@ -820,14 +819,14 @@ class FusedModelExecutor:
 
         if lanes is not None:
             # shared + profiles replicated, the request axis sharded in AND
-            # out; check_rep off because the per-shard scans never touch a
+            # out; check_vma off because the per-shard scans never touch a
             # replicated output.
-            body = shard_map(
+            body = jax.shard_map(
                 wave_body, mesh=dist_sharding.abstract_cores_mesh(lanes),
                 in_specs=(PartitionSpec(), PartitionSpec(),
                           dist_sharding.wave_spec()),
                 out_specs=dist_sharding.wave_spec(),
-                check_rep=False)
+                check_vma=False)
         else:
             body = wave_body
 

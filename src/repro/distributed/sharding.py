@@ -105,7 +105,7 @@ def abstract_cores_mesh(n_devices: int) -> AbstractMesh:
     the same size (one trace per (bucket, group size))."""
     if n_devices < 1:
         raise ValueError(f"abstract_cores_mesh({n_devices})")
-    return AbstractMesh(((CORES_AXIS, int(n_devices)),))
+    return AbstractMesh((int(n_devices),), (CORES_AXIS,))
 
 
 def wave_spec() -> P:

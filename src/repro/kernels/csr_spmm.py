@@ -9,6 +9,14 @@ column id -- delivered via scalar prefetch, exactly like the spdmm kernel's
 tile columns -- selects which dense row to DMA.  Steps beyond the row's count
 clamp their index map to the last valid slot (no new DMA) and ``pl.when``
 masks the FLOPs, so cost tracks the actual row fill, not the capacity.
+
+TPU tiling: a block's last two dims must be multiples of (8, 128) or equal
+the array's.  ``y`` and the output therefore carry a unit middle axis
+(``(k, 1, n)``), so a one-row ``(1, bn)`` block spans that axis whole, and
+each lhs row's ``rmax`` values arrive as one ``(1, rmax)`` block from which
+the step's value is selected by lane.  ``cols`` lives in SMEM by scalar
+prefetch (1 MiB on v5e), which bounds ``m * round_up(rmax, 128)`` at
+about 2^18 slots -- roughly 2000 rows at rmax <= 128.
 """
 from __future__ import annotations
 
@@ -31,8 +39,10 @@ def _csr_spmm_kernel(cols_ref, counts_ref, clamp_ref, vals_ref, y_ref, o_ref,
 
     @pl.when(s < counts_ref[i])
     def _mac():
-        acc_ref[...] += (vals_ref[0, 0].astype(jnp.float32)
-                         * y_ref[...].astype(jnp.float32))
+        row = vals_ref[...].astype(jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+        v = jnp.sum(jnp.where(lane == s, row, 0.0))
+        acc_ref[...] += v * y_ref[...].astype(jnp.float32)
 
     @pl.when(s == pl.num_programs(2) - 1)
     def _emit():
@@ -58,32 +68,31 @@ def csr_spmm(vals: jnp.ndarray, cols: jnp.ndarray, counts: jnp.ndarray,
     # Clamp masked steps to the last valid slot: same index -> no extra DMA.
     clamp = jnp.maximum(counts - 1, 0)  # (m,)
 
-    def v_index(i, j, s, cols_ref, counts_ref, clamp_ref):
-        del j, cols_ref, counts_ref
-        return (i, jnp.minimum(s, clamp_ref[i]))
-
     def y_index(i, j, s, cols_ref, counts_ref, clamp_ref):
         del counts_ref
-        return (cols_ref[i, jnp.minimum(s, clamp_ref[i])], j)
+        return (cols_ref[i, jnp.minimum(s, clamp_ref[i])], 0, j)
 
     if rmax == 0:  # zero-capacity lhs: keep one dummy (masked) slot
         vals = jnp.zeros((m, 1), vals.dtype)
         cols = jnp.zeros((m, 1), jnp.int32)
         rmax = 1
 
+    row = pl.Squeezed()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(m, nb, rmax),
         in_specs=[
-            pl.BlockSpec((1, 1), v_index),
-            pl.BlockSpec((1, bn), y_index),
+            pl.BlockSpec((row, 1, rmax), lambda i, j, s, *_: (i, 0, 0)),
+            pl.BlockSpec((row, 1, bn), y_index),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, s, *_: (i, j)),
+        out_specs=pl.BlockSpec((row, 1, bn), lambda i, j, s, *_: (i, 0, j)),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _csr_spmm_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, n), out_dtype),
         interpret=interpret,
-    )(cols, counts, clamp, vals, y)
+    )(cols, counts, clamp, vals.reshape(m, 1, rmax),
+      y.reshape(y.shape[0], 1, n))
+    return out.reshape(m, n)

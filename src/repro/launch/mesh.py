@@ -8,6 +8,7 @@ jax import, and nothing here may run before that.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # Latency-hiding / async-collective flags for REAL TPU runs (compute/comm
 # overlap).  The CPU dry-run ignores them; launch/train.py exports them.
@@ -27,11 +28,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     stays inside a pod's ICI)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_devices: int = 8, model: int = 4):
     """Small host-device mesh for unit tests (needs
     XLA_FLAGS=--xla_force_host_platform_device_count=<n> in the test env)."""
     data = n_devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

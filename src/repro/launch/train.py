@@ -18,6 +18,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_arch, smoke_config
 from repro.data.tokens import TokenPipeline
@@ -66,7 +67,8 @@ def main():
             over["n_layers"] = max(period, args.n_layers // period * period)
         cfg = smoke_config(args.arch, **over)
         n_dev = jax.device_count()
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = jax.make_mesh((n_dev, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
     bundle = model_zoo.build(cfg)
     opt = AdamW(lr=args.lr, warmup_steps=20, total_steps=args.steps,

@@ -4,13 +4,16 @@ The model IS its IR: ``core.compiler`` turns a ``GNNModelSpec`` + graph meta
 into Aggregate/Update kernels, and either the real-numerics engine
 (``core.runtime.DynasparseEngine``) or the cost-model simulator executes it.
 This module provides the bundle plumbing: weight init/pruning, dataset
-wiring, and the two evaluation paths used by tests/benchmarks/examples.
+wiring, the two evaluation paths used by tests/benchmarks/examples, and
+:func:`reference_forward`, the plain float32 ``jax.numpy`` forward every
+engine is checked against.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -54,6 +57,80 @@ def _glorot_pruned(kernels, *, seed: int, density: float
         w = rng.uniform(-lim, lim, size=(k.f_in, k.f_out)).astype(np.float32)
         out[k.rhs] = graph_data.prune_weights(w, density, rng)
     return out
+
+
+def _ref_dot(x, y):
+    return jnp.dot(x, y, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _ref_attention(a, z, att_src, att_dst, slope, threshold):
+    """Masked edge-softmax over the support of ``a`` (self loops included),
+    weights at or below ``threshold`` dropped; empty rows stay zero."""
+    s_src = _ref_dot(z, att_src)                       # (n, 1)
+    s_dst = _ref_dot(z, att_dst)                       # (n, 1)
+    scores = s_src + s_dst.T
+    scores = jnp.where(scores >= 0, scores, slope * scores)
+    support = a != 0
+    row_max = jnp.max(jnp.where(support, scores, -jnp.inf), axis=1,
+                      keepdims=True)
+    row_max = jnp.where(jnp.isfinite(row_max), row_max, 0.0)
+    ex = jnp.where(support, jnp.exp(scores - row_max), 0.0)
+    alpha = ex / jnp.maximum(jnp.sum(ex, axis=1, keepdims=True), 1e-30)
+    return jnp.where(alpha > threshold, alpha, 0.0)
+
+
+def reference_forward(spec: GNNModelSpec, tensors: Dict[str, jnp.ndarray]
+                      ) -> jnp.ndarray:
+    """Plain float32 forward of a zoo model, at ``Precision.HIGHEST``.
+
+    Written from the model definitions alone (no IR, no partitioning, no
+    K2P dispatch), so it checks the engines rather than restating them.
+    ``tensors`` holds ``A`` (GCN-normalized adjacency), ``A_mean``
+    (row-normalized), ``H0`` and the weights under the names
+    :func:`init_weights` gives them.  Returns the (n, n_classes) logits.
+    """
+    t = {k: jnp.asarray(v, jnp.float32) for k, v in tensors.items()}
+    a, h = t["A"], t["H0"]
+    model = spec.model.lower()
+    relu = jax.nn.relu
+    n_layers = spec.n_layers
+    for l in range(1, n_layers + 1):
+        act = (lambda v: v) if l == n_layers else relu
+        if model == "gcn":
+            h = act(_ref_dot(a, _ref_dot(h, t[f"W{l}"])))
+        elif model == "sage":
+            h = act(_ref_dot(_ref_dot(t["A_mean"], h), t[f"Wneigh{l}"])
+                    + _ref_dot(h, t[f"Wself{l}"]))
+        elif model == "gin":
+            n = _ref_dot(a, h) + (1.0 + spec.gin_eps) * h
+            h = act(_ref_dot(relu(_ref_dot(n, t[f"Wa{l}"])), t[f"Wb{l}"]))
+        elif model == "sgc":
+            if l == 1:
+                for _ in range(spec.sgc_hops):
+                    h = _ref_dot(a, h)
+            h = act(_ref_dot(h, t[f"W{l}"]))
+        elif model == "gat":
+            out = 0.0
+            for hd in range(1, spec.gat_heads + 1):
+                z = _ref_dot(h, t[f"Wg{l}h{hd}"])
+                alpha = _ref_attention(
+                    a, z, t[f"a_src{l}h{hd}"], t[f"a_dst{l}h{hd}"],
+                    spec.att_slope, spec.att_threshold)
+                out = out + _ref_dot(alpha, z)
+            h = act(out)
+        else:
+            raise ValueError(f"unknown GNN model {spec.model!r}")
+    return h
+
+
+def reference_error(out, ref) -> Tuple[float, float]:
+    """``(max |out - ref|, that / max |ref|)`` -- the error figures every
+    check against :func:`reference_forward` is gated on."""
+    out = np.asarray(out, np.float64)
+    ref = np.asarray(ref, np.float64)
+    err = float(np.max(np.abs(out - ref)))
+    return err, err / max(float(np.max(np.abs(ref))), 1e-30)
 
 
 def init_weights(compiled: CompiledModel, *, seed: int = 0,

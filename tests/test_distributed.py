@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import smoke_config
 from repro.distributed import sharding
@@ -18,13 +19,18 @@ from repro.models import model_zoo
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# jax.make_mesh defaults to Explicit axes; these meshes use GSPMD's Auto mode
+AUTO2 = (AxisType.Auto,) * 2
+_PRELUDE = "from jax.sharding import AxisType\nAUTO2 = (AxisType.Auto,) * 2\n"
+
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(REPO, "src"))
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    code = _PRELUDE + textwrap.dedent(code)
+    out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env,
                          timeout=560)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -32,7 +38,7 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
 
 
 def test_param_spec_rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
 
     class FakeMesh:
         axis_names = ("data", "model")
@@ -79,7 +85,7 @@ def test_multidevice_train_step_runs():
         from repro.train.optimizer import AdamW
         from repro.train.trainer import TrainState, make_train_step
         cfg = smoke_config("llama3-8b", n_layers=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         bundle = model_zoo.build(cfg)
         opt = AdamW(lr=1e-3)
         step = make_train_step(bundle.loss_fn, opt, num_microbatches=2)
@@ -106,11 +112,11 @@ def test_multidevice_elastic_reshard(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
-        m1 = jax.make_mesh((4, 2), ("data", "model"))
+        m1 = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(m1, P("data", "model")))
         ckpt.save(r"{tmp_path}", 3, {{"x": xs}})
-        m2 = jax.make_mesh((2, 4), ("data", "model"))
+        m2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         sh = {{"x": NamedSharding(m2, P("data", "model"))}}
         got, step = ckpt.restore(r"{tmp_path}", {{"x": x}}, shardings=sh)
         assert step == 3
@@ -124,18 +130,17 @@ def test_multidevice_compressed_allreduce():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.collectives import (
             compressed_grad_allreduce, init_residual)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         res = jnp.zeros((8, 64))
         def f(gl, rl):
             m, r = compressed_grad_allreduce({"g": gl[0]}, "data",
                                              {"g": rl[0]})
             return m["g"][None], r["g"][None]
-        fm = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")))
+        fm = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")))
         mean, new_res = fm(g, res)
         want = jnp.mean(g, axis=0)
         got = np.asarray(mean[0])
@@ -159,7 +164,7 @@ def test_dryrun_cell_on_test_mesh():
         from repro.launch import dryrun
         cfg = smoke_config("llama3-8b", n_layers=2)
         shape = ShapeCfg("t", 64, 8, "train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         compiled, tl, tc = dryrun.compile_cell(cfg, shape, mesh)
         ca = dryrun.cost_analysis_dict(compiled)
         coll = dryrun.collective_bytes(compiled.as_text())
@@ -177,7 +182,7 @@ def test_decode_cell_on_test_mesh():
         from repro.launch import dryrun
         cfg = smoke_config("jamba-v0.1-52b")
         shape = ShapeCfg("d", 128, 8, "decode")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         compiled, tl, tc = dryrun.compile_cell(cfg, shape, mesh)
         ca = dryrun.cost_analysis_dict(compiled)
         print("DECODE DRYRUN OK", int(ca["flops"]))

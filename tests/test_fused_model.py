@@ -56,6 +56,31 @@ def test_fused_matches_per_kernel_bitwise(model):
                 err_msg=f"{model}/{strategy}/{out}: planner codes differ")
 
 
+# reference gate, as a fraction of max |reference| (float32 on the CPU)
+REF_TOL = 1e-4
+
+
+@pytest.mark.parametrize("model", gnn_models.GNN_MODELS)
+def test_fused_matches_float32_reference(model):
+    """The fused executor's logits agree with the plain float32 forward
+    (``gnn.reference_forward``), and the tolerance is tight enough that a
+    forward which skips every aggregation (A := I) fails it by far."""
+    b = gnn_models.build_dense(model, "CO", scale=0.12, seed=2)
+    out, _ = b.run(runtime.FusedModelExecutor(strategy="dynamic"))
+    spec = gnn_models.make_model_spec(
+        model, b.graph.spec.f_in, b.graph.spec.hidden, b.graph.spec.n_classes)
+    ref = gnn_models.reference_forward(spec, b.tensors)
+    assert out.shape == ref.shape == (b.graph.spec.n_vertices,
+                                      b.graph.spec.n_classes)
+    _, rel = gnn_models.reference_error(out, ref)
+    assert rel <= REF_TOL, (model, rel)
+    eye = jnp.eye(ref.shape[0], dtype=jnp.float32)
+    no_agg = gnn_models.reference_forward(
+        spec, {**b.tensors, "A": eye, "A_mean": eye})
+    _, rel_no_agg = gnn_models.reference_error(no_agg, ref)
+    assert rel_no_agg > 100 * REF_TOL, (model, rel_no_agg)
+
+
 @pytest.mark.parametrize("strategy", STRATEGIES)
 def test_fused_report_matches_per_kernel(strategy):
     _, (_, _, rep_p), (_, _, rep_f) = _run_both("gcn", strategy)

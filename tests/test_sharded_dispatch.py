@@ -28,6 +28,7 @@ import textwrap
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.distributed import sharding
 from repro.serving.graph_engine import GraphServeEngine, random_requests
@@ -154,7 +155,8 @@ def test_invalid_mesh_and_slots_rejected():
 
     with pytest.raises(ValueError, match="not divisible"):
         _engine(mesh=TwoDeviceMeshStub(), slots=3)
-    eng = _engine(mesh=jax.make_mesh((1,), ("notcores",)), slots=4)
+    mesh = jax.make_mesh((1,), ("notcores",), axis_types=(AxisType.Auto,))
+    eng = _engine(mesh=mesh, slots=4)
     with pytest.raises(ValueError, match="cores"):
         eng.serve(_reqs(1))
     with pytest.raises(ValueError):

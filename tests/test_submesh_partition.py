@@ -28,6 +28,7 @@ import textwrap
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.distributed import sharding
 from repro.serving.scheduler import plan_groups
@@ -171,7 +172,9 @@ def test_partition_mesh_validates_axis_and_single_device():
     """partition_mesh demands a 1-D cores mesh; the 1-device partition
     (tier-1's whole visible world) round-trips."""
     with pytest.raises(ValueError, match="cores"):
-        sharding.partition_mesh(jax.make_mesh((1,), ("notcores",)), [1])
+        mesh = jax.make_mesh((1,), ("notcores",),
+                             axis_types=(AxisType.Auto,))
+        sharding.partition_mesh(mesh, [1])
     [sub] = sharding.partition_mesh(sharding.cores_mesh(1), [1])
     assert sub.devices.size == 1
     assert sub.axis_names == (sharding.CORES_AXIS,)
