@@ -927,8 +927,6 @@ def _bench_minibatch(model: str, n_vertices: int, n_queries: int, *,
         "hit_rate": stats.hit_rate,
         "waves": eng.waves - waves0,
         "padding_efficiency": _padding_efficiency(eng.wave_loads[w0:]),
-        "gather_seconds": (float(eng.last_wave_report.gather_seconds)
-                           if eng.last_wave_report is not None else 0.0),
         "naive_throughput_sps": n_seed_runs / t_naive,
         "minibatch_throughput_sps": n_seed_runs / t_mb,
     }

@@ -201,13 +201,14 @@ def dynasparse_matmul(
         dens_x = _blocked_density(xb, x.shape[0], x.shape[1])   # (I, K)
     if dens_y is None:
         dens_y = _blocked_density(yb, y.shape[0], y.shape[1])   # (K, J)
-    if codes is None:
-        codes = analyzer.plan_codes(strategy, dens_x, dens_y, cost_model,
-                                    kernel_type=kernel_type)
-    if format_aware and fmt is None:
-        fmt = analyzer.plan_format(strategy, dens_x, dens_y, x.shape, n,
-                                   block, cost_model,
-                                   kernel_type=kernel_type, rmax=csr_rmax)
+    with jax.named_scope("k2p_plan"):
+        if codes is None:
+            codes = analyzer.plan_codes(strategy, dens_x, dens_y, cost_model,
+                                        kernel_type=kernel_type)
+        if format_aware and fmt is None:
+            fmt = analyzer.plan_format(strategy, dens_x, dens_y, x.shape, n,
+                                       block, cost_model,
+                                       kernel_type=kernel_type, rmax=csr_rmax)
 
     out_dtype = jnp.promote_types(x.dtype, y.dtype)
     if residual is not None:
@@ -263,7 +264,8 @@ def dynasparse_matmul(
         # the conversion lossless-or-ignored: if any row overflows csr_rmax
         # (the planner's fill-slack guess was wrong), fall back to blocks.
         if ell is None:
-            ell = ell_when(fmt, x, csr_rmax)
+            with jax.named_scope("d2s"):
+                ell = ell_when(fmt, x, csr_rmax)
         fits = jnp.max(ell.row_counts) <= csr_rmax
         use_csr = jnp.logical_and(fmt == Format.CSR, fits)
 
@@ -294,8 +296,9 @@ def dynasparse_matmul(
 
     # --- Sparsity Profiler fused at writeback (Section V-B2) ---
     ob = out_block or (bm, bn)
-    out_counts = profiler.block_counts(out, ob)
-    out_density = profiler.density_from_counts(out_counts, m, n, *ob)
+    with jax.named_scope("writeback_profile"):
+        out_counts = profiler.block_counts(out, ob)
+        out_density = profiler.density_from_counts(out_counts, m, n, *ob)
     return DynasparseResult(out.astype(out_dtype), codes, dens_x, dens_y,
                             out_density, out_counts, executed_fmt)
 
@@ -376,8 +379,10 @@ def attention_adjacency(
     alpha = ex / denom
     alpha = jnp.where(alpha > threshold, alpha, 0.0).astype(out_dtype)
 
-    out_counts = profiler.block_counts(alpha, out_block)
-    out_density = profiler.density_from_counts(out_counts, m, m, *out_block)
+    with jax.named_scope("writeback_profile"):
+        out_counts = profiler.block_counts(alpha, out_block)
+        out_density = profiler.density_from_counts(out_counts, m, m,
+                                                   *out_block)
     one = jnp.ones((1, 1), jnp.float32)
     codes = jnp.full((1, 1, 1), Primitive.GEMM, jnp.int32)
     return DynasparseResult(alpha, codes, one, one, out_density, out_counts,

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
+from repro import spans
 from repro.core import analyzer, profiler, scheduler
 from repro.core import formats as _formats
 from repro.distributed import sharding as dist_sharding
@@ -90,12 +91,6 @@ class InferenceReport:
     # lane count the wave was dispatched over (1 when unsharded): the size
     # of the ``cores`` mesh axis run_batch sharded the request scan across.
     wave_lanes: int = 1
-    # host seconds spent filling this wave's slot buffers (normalize +
-    # feature gather -- for store-backed mini-batch requests this is the
-    # per-wave gather from the pinned FeatureStore into the bucket-padded
-    # slots, DESIGN.md section 16).  Stamped by the admission layer like
-    # wave_real; 0.0 on non-wave paths.
-    gather_seconds: float = 0.0
 
     @property
     def total_cycles(self) -> float:
@@ -674,75 +669,83 @@ class FusedModelExecutor:
         ell_env: Dict[tuple, tuple] = {}
         sides = []
         for k, (fx, fy) in zip(kernels, flows):
-            x, y = env[fx.source], env[fy.source]
-            if k.kernel_type == KernelType.ATTENTION:
-                # masked edge-softmax (GAT): no K2P planning of its own --
-                # its whole point is that the OUTPUT density is unknowable
-                # before execution.  The writeback profile it emits is what
-                # the downstream Aggregate plans from, per head.
-                n2a = k.scheme.n2
-                res = attention_adjacency(
-                    x, y, env[k.att_src], env[k.att_dst],
-                    slope=k.att_slope, threshold=k.att_threshold,
-                    out_block=(n2a, n2a))
-                env[k.out] = res.out
-                counts_env[k.out] = profiler.BlockProfile(
-                    res.out_counts, res.out.shape, (n2a, n2a))
-                sides.append((res.codes, res.dens_x, res.dens_y,
-                              res.out_density, res.fmt))
-                continue
-            prof_x, prof_y = (
-                counts_env[f.source].pool_rows(f.pool_rows)
-                          .pool_cols(f.pool_cols)
-                if f.producer is not None else profiles[(f.source, f.block)]
-                for f in (fx, fy))
-            codes, dens_x, dens_y = analyzer.plan_codes_from_profiles(
-                self.strategy, prof_x, prof_y, self.model,
-                kernel_type=k.kernel_type)
-            fmt = None
-            ell = None
-            if self.format_aware:
-                fmt = analyzer.plan_format(
-                    self.strategy, dens_x, dens_y, x.shape, y.shape[1],
-                    k.block_dims, self.model, kernel_type=k.kernel_type,
-                    rmax=self.csr_rmax)
+            # stable names for the device trace: the kernel (aggregate,
+            # update or attention), and inside it k2p_plan, d2s and
+            # dynasparse_matmul's writeback_profile
+            with jax.named_scope(k.kernel_type.name.lower()):
+                x, y = env[fx.source], env[fy.source]
+                if k.kernel_type == KernelType.ATTENTION:
+                    # masked edge-softmax (GAT): no K2P planning of its own
+                    # -- its whole point is that the OUTPUT density is
+                    # unknowable before execution.  The writeback profile
+                    # it emits is what the downstream Aggregate plans from,
+                    # per head.
+                    n2a = k.scheme.n2
+                    res = attention_adjacency(
+                        x, y, env[k.att_src], env[k.att_dst],
+                        slope=k.att_slope, threshold=k.att_threshold,
+                        out_block=(n2a, n2a))
+                    env[k.out] = res.out
+                    counts_env[k.out] = profiler.BlockProfile(
+                        res.out_counts, res.out.shape, (n2a, n2a))
+                    sides.append((res.codes, res.dens_x, res.dens_y,
+                                  res.out_density, res.fmt))
+                    continue
+                prof_x, prof_y = (
+                    counts_env[f.source].pool_rows(f.pool_rows)
+                                        .pool_cols(f.pool_cols)
+                    if f.producer is not None
+                    else profiles[(f.source, f.block)]
+                    for f in (fx, fy))
+                fmt = None
+                ell = None
+                with jax.named_scope("k2p_plan"):
+                    codes, dens_x, dens_y = analyzer.plan_codes_from_profiles(
+                        self.strategy, prof_x, prof_y, self.model,
+                        kernel_type=k.kernel_type)
+                    if self.format_aware:
+                        fmt = analyzer.plan_format(
+                            self.strategy, dens_x, dens_y, x.shape,
+                            y.shape[1], k.block_dims, self.model,
+                            kernel_type=k.kernel_type, rmax=self.csr_rmax)
                 if fmt is not None:
                     ekey = (fx.source, tuple(x.shape))
                     prev = ell_env.get(ekey)
-                    if prev is None:
-                        ell = ell_when(fmt, x, self.csr_rmax)
-                        want = fmt
-                    else:
-                        prev_want, prev_ell = prev
-                        ell = jax.lax.cond(
-                            jnp.logical_and(fmt == Format.CSR,
-                                            prev_want != Format.CSR),
-                            lambda x=x: _formats.dense_to_ell(
-                                x, rmax=self.csr_rmax),
-                            lambda: prev_ell)
-                        want = jnp.maximum(prev_want, fmt)
+                    with jax.named_scope("d2s"):
+                        if prev is None:
+                            ell = ell_when(fmt, x, self.csr_rmax)
+                            want = fmt
+                        else:
+                            prev_want, prev_ell = prev
+                            ell = jax.lax.cond(
+                                jnp.logical_and(fmt == Format.CSR,
+                                                prev_want != Format.CSR),
+                                lambda x=x: _formats.dense_to_ell(
+                                    x, rmax=self.csr_rmax),
+                                lambda: prev_ell)
+                            want = jnp.maximum(prev_want, fmt)
                     ell_env[ekey] = (want, ell)
-            residual = (env[k.epilogue_add]
-                        if k.epilogue_add is not None else None)
-            n2 = k.scheme.n2
-            res = dynasparse_matmul(
-                x, y, codes=codes, dens_x=dens_x, dens_y=dens_y,
-                fmt=fmt, ell=ell,
-                residual=residual, strategy=self.strategy,
-                kernel_type=k.kernel_type,
-                epilogue_scale=(k.epilogue_scale
-                                if residual is not None else 1.0),
-                activation=(k.activation.value
-                            if k.activation_enabled else "none"),
-                out_block=(n2, n2), block=k.block_dims,
-                cost_model=self.model, use_kernels=self.use_kernels,
-                tile=self.tile, unroll=self.unroll,
-                format_aware=self.format_aware, csr_rmax=self.csr_rmax)
-            env[k.out] = res.out
-            counts_env[k.out] = profiler.BlockProfile(
-                res.out_counts, res.out.shape, (n2, n2))
-            sides.append((res.codes, res.dens_x, res.dens_y,
-                          res.out_density, res.fmt))
+                residual = (env[k.epilogue_add]
+                            if k.epilogue_add is not None else None)
+                n2 = k.scheme.n2
+                res = dynasparse_matmul(
+                    x, y, codes=codes, dens_x=dens_x, dens_y=dens_y,
+                    fmt=fmt, ell=ell,
+                    residual=residual, strategy=self.strategy,
+                    kernel_type=k.kernel_type,
+                    epilogue_scale=(k.epilogue_scale
+                                    if residual is not None else 1.0),
+                    activation=(k.activation.value
+                                if k.activation_enabled else "none"),
+                    out_block=(n2, n2), block=k.block_dims,
+                    cost_model=self.model, use_kernels=self.use_kernels,
+                    tile=self.tile, unroll=self.unroll,
+                    format_aware=self.format_aware, csr_rmax=self.csr_rmax)
+                env[k.out] = res.out
+                counts_env[k.out] = profiler.BlockProfile(
+                    res.out_counts, res.out.shape, (n2, n2))
+                sides.append((res.codes, res.dens_x, res.dens_y,
+                              res.out_density, res.fmt))
         return sides
 
     def _build(self, compiled: CompiledModel) -> tuple:
@@ -873,29 +876,33 @@ class FusedModelExecutor:
         plus ``fused_wall_seconds`` (the single program's wall clock).
         """
         n_cc = self.n_cc or compiled.partition.n_cc
-        fn, needed = self._program(compiled, tensors)
-        in_counts = self._input_counts(needed, tensors)
-        t0 = time.perf_counter()
-        outs, sides = fn(tensors, in_counts)
-        jax.block_until_ready((outs, sides))
+        with spans.span("runtime.dispatch"):
+            fn, needed = self._program(compiled, tensors)
+            in_counts = self._input_counts(needed, tensors)
+            t0 = time.perf_counter()
+            outs, sides = fn(tensors, in_counts)
+        with spans.span("runtime.wait"):
+            jax.block_until_ready((outs, sides))
         wall = time.perf_counter() - t0
 
-        self.profiled_densities = {
-            k.out: side[3]
-            for k, side in zip(compiled.graph.topo_order(), sides)}
-        if self.keep_codes:
-            self.planned_codes = {
-                k.out: np.asarray(side[0])
+        with spans.span("runtime.report"):
+            self.profiled_densities = {
+                k.out: side[3]
                 for k, side in zip(compiled.graph.topo_order(), sides)}
-            self.planned_formats = {
-                k.out: np.asarray(side[4])
-                for k, side in zip(compiled.graph.topo_order(), sides)}
-        reports = []
-        if self.collect_report:
-            reports = [
-                _bookkeep_kernel(k, codes, dens_x, dens_y, n_cc, self.model)
-                for k, (codes, dens_x, dens_y, _, _fmt) in
-                zip(compiled.graph.topo_order(), sides)]
+            if self.keep_codes:
+                self.planned_codes = {
+                    k.out: np.asarray(side[0])
+                    for k, side in zip(compiled.graph.topo_order(), sides)}
+                self.planned_formats = {
+                    k.out: np.asarray(side[4])
+                    for k, side in zip(compiled.graph.topo_order(), sides)}
+            reports = []
+            if self.collect_report:
+                reports = [
+                    _bookkeep_kernel(k, codes, dens_x, dens_y, n_cc,
+                                     self.model)
+                    for k, (codes, dens_x, dens_y, _, _fmt) in
+                    zip(compiled.graph.topo_order(), sides)]
         return outs, InferenceReport(reports, self.strategy,
                                      fused_wall_seconds=wall)
 
@@ -914,87 +921,95 @@ class FusedModelExecutor:
         dispatch lanes); the pending wave's wall clock runs from launch to
         ready, so queue time behind earlier in-flight waves is measured,
         not hidden."""
-        n_cc = self.n_cc or compiled.partition.n_cc
-        flows = self._resolved_flows(compiled)
-        needed = self._needed_inputs(flows)
-        missing = [n for n, _ in needed
-                   if n not in shared and n not in batched]
-        if missing:
-            raise KeyError(f"wave inputs missing tensors: {missing}")
-        shared_needed = tuple((n, b) for n, b in needed if n in shared)
-        request_needed = tuple((n, b) for n, b in needed if n in batched)
+        with spans.span("runtime.dispatch"):
+            n_cc = self.n_cc or compiled.partition.n_cc
+            flows = self._resolved_flows(compiled)
+            needed = self._needed_inputs(flows)
+            missing = [n for n, _ in needed
+                       if n not in shared and n not in batched]
+            if missing:
+                raise KeyError(f"wave inputs missing tensors: {missing}")
+            shared_needed = tuple((n, b) for n, b in needed if n in shared)
+            request_needed = tuple((n, b) for n, b in needed if n in batched)
 
-        lanes = 1
-        if mesh is not None:
-            if (len(mesh.axis_names) != 1
-                    or mesh.axis_names[0] != dist_sharding.CORES_AXIS):
-                raise ValueError(
-                    f"run_batch mesh must be 1-D over "
-                    f"{dist_sharding.CORES_AXIS!r}, got {mesh.axis_names}")
-            lanes = int(mesh.devices.size)
-            b = int(next(iter(batched.values())).shape[0])
-            if b % lanes:
-                raise ValueError(
-                    f"wave of {b} slots not divisible by {lanes} mesh "
-                    f"devices")
+            lanes = 1
+            if mesh is not None:
+                if (len(mesh.axis_names) != 1
+                        or mesh.axis_names[0] != dist_sharding.CORES_AXIS):
+                    raise ValueError(
+                        f"run_batch mesh must be 1-D over "
+                        f"{dist_sharding.CORES_AXIS!r}, got {mesh.axis_names}")
+                lanes = int(mesh.devices.size)
+                b = int(next(iter(batched.values())).shape[0])
+                if b % lanes:
+                    raise ValueError(
+                        f"wave of {b} slots not divisible by {lanes} mesh "
+                        f"devices")
 
-        # the shard_map program is traced against the ABSTRACT cores mesh
-        # (concrete devices bind at call time from the inputs' shardings),
-        # so the key carries only the GROUP SIZE: disjoint same-size device
-        # groups -- partition_mesh lanes -- share one compiled program, and
-        # the trace bound is one per (bucket, group size).
-        key = ("wave", None if mesh is None else lanes,
-               self._signature(compiled, shared), self._tensor_sig(batched))
-        fn = self._programs.get(key)
-        if fn is not None:
-            self.cache_hits += 1
-        else:
-            self.cache_misses += 1
-            fn = self._build_batch(compiled, shared_needed, request_needed,
-                                   lanes=None if mesh is None else lanes)
-            self._programs[key] = fn
+            # the shard_map program is traced against the ABSTRACT cores
+            # mesh (concrete devices bind at call time from the inputs'
+            # shardings), so the key carries only the GROUP SIZE: disjoint
+            # same-size device groups -- partition_mesh lanes -- share one
+            # compiled program, and the trace bound is one per (bucket,
+            # group size).
+            key = ("wave", None if mesh is None else lanes,
+                   self._signature(compiled, shared),
+                   self._tensor_sig(batched))
+            fn = self._programs.get(key)
+            if fn is not None:
+                self.cache_hits += 1
+            else:
+                self.cache_misses += 1
+                fn = self._build_batch(compiled, shared_needed, request_needed,
+                                       lanes=None if mesh is None else lanes)
+                self._programs[key] = fn
 
-        if mesh is not None:
-            # commit the stacked request tensors to their wave sharding up
-            # front: host-side stacks transfer as one host->shard split per
-            # device instead of staging the full stack on one device and
-            # resharding from there.
-            batched = jax.device_put(
-                batched, dist_sharding.wave_shardings(mesh, batched))
+            if mesh is not None:
+                # commit the stacked request tensors to their wave sharding
+                # up front: host-side stacks transfer as one host->shard
+                # split per device instead of staging the full stack on one
+                # device and resharding from there.
+                batched = jax.device_put(
+                    batched, dist_sharding.wave_shardings(mesh, batched))
 
-        shared_counts = self._input_counts(shared_needed, shared)
-        b_sz = int(next(iter(batched.values())).shape[0])
-        t0 = time.perf_counter()
-        outs, sides = fn(shared, shared_counts, batched)
-        return PendingWave(outs=outs, sides=sides, compiled=compiled,
-                           n_cc=n_cc, lanes=lanes, wave_slots=b_sz,
-                           launched_at=t0)
+            shared_counts = self._input_counts(shared_needed, shared)
+            b_sz = int(next(iter(batched.values())).shape[0])
+            t0 = time.perf_counter()
+            outs, sides = fn(shared, shared_counts, batched)
+            return PendingWave(outs=outs, sides=sides, compiled=compiled,
+                               n_cc=n_cc, lanes=lanes, wave_slots=b_sz,
+                               launched_at=t0)
 
     def finish_batch(self, pending: "PendingWave"
                      ) -> Tuple[Dict[str, jnp.ndarray], InferenceReport]:
         """Block on a :meth:`launch_batch` wave and assemble its report
         (the synchronous half of :meth:`run_batch`)."""
         outs, sides = pending.outs, pending.sides
-        jax.block_until_ready((outs, sides))
+        with spans.span("runtime.wait"):
+            jax.block_until_ready((outs, sides))
         wall = time.perf_counter() - pending.launched_at
 
-        topo = pending.compiled.graph.topo_order()
-        self.profiled_densities = {
-            k.out: side[3] for k, side in zip(topo, sides)}   # (B, ...)
-        if self.keep_codes:
-            self.planned_codes = {
-                k.out: np.asarray(side[0]) for k, side in zip(topo, sides)}
-            self.planned_formats = {
-                k.out: np.asarray(side[4])  # (B,) executed Format per slot
-                for k, side in zip(topo, sides)}
-        reports = []
-        if self.collect_report:
-            for b in range(pending.wave_slots):
-                for k, (codes, dens_x, dens_y, _, _fmt) in zip(topo, sides):
-                    rep = _bookkeep_kernel(k, codes[b], dens_x[b], dens_y[b],
-                                           pending.n_cc, self.model)
-                    rep.name = f"{k.name}[{b}]"
-                    reports.append(rep)
+        with spans.span("runtime.report"):
+            topo = pending.compiled.graph.topo_order()
+            self.profiled_densities = {
+                k.out: side[3] for k, side in zip(topo, sides)}  # (B, ...)
+            if self.keep_codes:
+                self.planned_codes = {
+                    k.out: np.asarray(side[0])
+                    for k, side in zip(topo, sides)}
+                self.planned_formats = {
+                    k.out: np.asarray(side[4])  # (B,) executed Format/slot
+                    for k, side in zip(topo, sides)}
+            reports = []
+            if self.collect_report:
+                for b in range(pending.wave_slots):
+                    for k, (codes, dens_x, dens_y, _, _fmt) in zip(topo,
+                                                                  sides):
+                        rep = _bookkeep_kernel(
+                            k, codes[b], dens_x[b], dens_y[b], pending.n_cc,
+                            self.model)
+                        rep.name = f"{k.name}[{b}]"
+                        reports.append(rep)
         return outs, InferenceReport(reports, self.strategy,
                                      fused_wall_seconds=wall,
                                      wave_slots=pending.wave_slots,

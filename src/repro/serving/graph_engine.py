@@ -43,13 +43,13 @@ throughput) and gates CI on the batched path staying ahead.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import spans
 from repro.core import compiler, runtime
 from repro.core import scheduler as core_scheduler
 from repro.core.compiler import CompiledModel, GraphMeta
@@ -109,8 +109,6 @@ class InFlightWave:
     pending: runtime.PendingWave
     final: str                      # env name of the model's output tensor
     index: int                      # admission wave index (GraphResult.wave)
-    gather_seconds: float = 0.0     # host wall filling the slot buffers
-    #                                 (normalize + feature gather/copy)
 
 
 def random_requests(n_requests: int, *, f_in: int,
@@ -522,42 +520,41 @@ class GraphServeEngine:
         if not 0 < len(wave) <= self.slots:
             raise ValueError(
                 f"wave of {len(wave)} requests (engine slots={self.slots})")
-        mesh = self.mesh if submesh is None else submesh
-        lanes = 1 if mesh is None else int(mesh.devices.size)
-        if submesh is not None and self.slots % lanes:
-            raise ValueError(
-                f"slots={self.slots} not divisible by the {lanes}-device "
-                f"submesh group")
-        cm = self._compile(bucket)
-        slot_of = self._slot_layout(wave, lanes)
-        # ONE zero-initialized (slots, ...) buffer per graph input, filled
-        # slot-by-slot in place: dummy slots stay all-zero (all-SKIP
-        # plans) with no per-slot dict or np.stack copy, and store-backed
-        # requests gather their feature rows straight into their slot
-        # (``_fill_slot``'s fill_features hook).  The fill wall is the
-        # wave's per-wave gather cost (InferenceReport.gather_seconds).
-        t0 = time.perf_counter()
-        batched = {name: np.zeros(
-            (self.slots,) + self._input_shape(name, bucket), np.float32)
-            for name in self._input_names[bucket]}
-        for req, slot in zip(wave, slot_of):
-            self._fill_slot(req, {name: buf[slot]
-                                  for name, buf in batched.items()})
-        gather_seconds = time.perf_counter() - t0
-        # sharded waves stay host-side here: launch_batch device_puts them
-        # straight onto the mesh (one host->per-device-shard transfer);
-        # staging through jnp.asarray first would land the full stack on
-        # one device and reshard from there.
-        if mesh is None:
-            batched = {name: jnp.asarray(v) for name, v in batched.items()}
-        pending = self.executor.launch_batch(cm, self.weights, batched,
-                                             mesh=mesh)
         index = self.waves
+        with spans.span("serve.launch", wave=index, bucket=bucket):
+            mesh = self.mesh if submesh is None else submesh
+            lanes = 1 if mesh is None else int(mesh.devices.size)
+            if submesh is not None and self.slots % lanes:
+                raise ValueError(
+                    f"slots={self.slots} not divisible by the {lanes}-device "
+                    f"submesh group")
+            cm = self._compile(bucket)
+            slot_of = self._slot_layout(wave, lanes)
+            # ONE zero-initialized (slots, ...) buffer per graph input,
+            # filled slot-by-slot in place: dummy slots stay all-zero
+            # (all-SKIP plans) with no per-slot dict or np.stack copy, and
+            # store-backed requests gather their feature rows straight
+            # into their slot (``_fill_slot``'s fill_features hook).
+            with spans.span("serve.fill", wave=index):
+                batched = {name: np.zeros(
+                    (self.slots,) + self._input_shape(name, bucket),
+                    np.float32) for name in self._input_names[bucket]}
+                for req, slot in zip(wave, slot_of):
+                    self._fill_slot(req, {name: buf[slot]
+                                          for name, buf in batched.items()})
+            # sharded waves stay host-side here: launch_batch device_puts
+            # them straight onto the mesh (one host->per-device-shard
+            # transfer); staging through jnp.asarray first would land the
+            # full stack on one device and reshard from there.
+            if mesh is None:
+                batched = {name: jnp.asarray(v)
+                           for name, v in batched.items()}
+            pending = self.executor.launch_batch(cm, self.weights, batched,
+                                                 mesh=mesh)
         self.waves += 1
         return InFlightWave(bucket=bucket, wave=list(wave), slot_of=slot_of,
                             pending=pending,
-                            final=cm.graph.kernels[-1].out, index=index,
-                            gather_seconds=gather_seconds)
+                            final=cm.graph.kernels[-1].out, index=index)
 
     def finish_wave(self, inflight: "InFlightWave") -> List[GraphResult]:
         """Block on a :meth:`begin_wave` launch, record the serving
@@ -567,7 +564,6 @@ class GraphServeEngine:
         back out (wave order)."""
         outs, rep = self.executor.finish_batch(inflight.pending)
         rep.wave_real = len(inflight.wave)
-        rep.gather_seconds = inflight.gather_seconds
         self.last_wave_report = rep
         arr = np.asarray(outs[inflight.final])
         results = [GraphResult(req.request_id, arr[slot, : req.n_vertices],

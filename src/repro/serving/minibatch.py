@@ -11,8 +11,8 @@ the induced subgraph flows through a wave.  This module is that front end:
 * :class:`FeatureStore` -- the giant graph's features held ONCE, pinned
   host-side; per-wave gather copies just the sampled rows into the
   bucket-padded wave slots (``GraphServeEngine._fill_slot`` calls
-  ``SeedRequest.fill_features`` straight into the slot view, and the
-  engine's per-wave ``gather_seconds`` measures the cost).  ``update``
+  ``SeedRequest.fill_features`` straight into the slot view; the
+  engine's ``serve.fill`` span times it under a profiler).  ``update``
   bumps a version counter and notifies listeners -- the cache
   invalidation hook.
 
@@ -49,6 +49,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core import analyzer
 from repro.core.perf_model import FPGACostModel
 from repro.data.sampling import (AdjacencyBlockProfile, GraphDelta, HostGraph,
@@ -359,7 +360,9 @@ class MiniBatchPlanner:
     def request_for(self, vertex: int) -> SeedRequest:
         """A fresh store-backed request for ``vertex`` (tracked in flight
         until :meth:`complete` sees its result)."""
-        req = SeedRequest(self.sample(vertex), self.store, self._next_rid)
+        with spans.span("serve.sample", request=self._next_rid):
+            req = SeedRequest(self.sample(vertex), self.store,
+                              self._next_rid)
         req.graph_version = self.graph_version
         self._next_rid -= 1
         self._inflight[req.request_id] = req
@@ -599,9 +602,6 @@ class MiniBatchServeEngine:
         }
         walls = self.engine.wave_walls
         rep["wave_wall_seconds"] = float(np.sum(walls)) if walls else 0.0
-        last = self.engine.last_wave_report
-        if last is not None and getattr(last, "gather_seconds", None):
-            rep["last_gather_seconds"] = float(last.gather_seconds)
         if self.cache is not None:
             rep["cache"] = self.cache.stats.as_dict()
         return rep

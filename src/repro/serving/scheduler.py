@@ -88,12 +88,13 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core import perf_model
 from repro.core import scheduler as core_scheduler
 from repro.distributed import sharding as dist_sharding
 from repro.serving.config import UNSET, ServeConfig, merge_config
 from repro.serving.graph_engine import (GraphRequest, GraphResult,
-                                        GraphServeEngine)
+                                        GraphServeEngine, InFlightWave)
 
 
 def plan_groups(n_devices: int, demands: Sequence[float], slots: int,
@@ -643,37 +644,38 @@ class ContinuousGraphServer:
                 "submit_query needs a minibatch planner: "
                 "ContinuousGraphServer(engine, "
                 "minibatch=MiniBatchPlanner(graph, store, ...))")
-        qt = QueryTicket(self._query_seq, [int(v) for v in seeds],
-                         deadline=deadline)
-        self._query_seq += 1
-        self.queries_submitted += 1
-        for v in dict.fromkeys(qt.seeds):
-            row = planner.lookup(v)
-            if row is not None:
-                qt.from_cache += 1
-                qt._fill(v, row)
-                continue
-            qt._pending.add(v)
-            rid = self._inflight_seed.get(v)
-            if rid is not None and rid in self._query_waiters:
-                inflight = planner.inflight_request(rid)
-                if (inflight is not None and inflight.store_version
-                        == planner.store.version
-                        and inflight.graph_version
-                        == planner.graph_version):
-                    self._query_waiters[rid].append(qt)
+        with spans.span("serve.submit", query=self._query_seq):
+            qt = QueryTicket(self._query_seq, [int(v) for v in seeds],
+                             deadline=deadline)
+            self._query_seq += 1
+            self.queries_submitted += 1
+            for v in dict.fromkeys(qt.seeds):
+                row = planner.lookup(v)
+                if row is not None:
+                    qt.from_cache += 1
+                    qt._fill(v, row)
                     continue
-            req = planner.request_for(v)
-            ticket = self.submit(req, deadline, priority=priority,
-                                 tenant=tenant)
-            qt.tickets.append(ticket)
-            if not ticket.admitted:
-                planner.abandon(req)
-                qt.shed_seeds.append(v)
-                qt._fill(v, None)
-                continue
-            self._query_waiters[req.request_id] = [qt]
-            self._inflight_seed[v] = req.request_id
+                qt._pending.add(v)
+                rid = self._inflight_seed.get(v)
+                if rid is not None and rid in self._query_waiters:
+                    inflight = planner.inflight_request(rid)
+                    if (inflight is not None and inflight.store_version
+                            == planner.store.version
+                            and inflight.graph_version
+                            == planner.graph_version):
+                        self._query_waiters[rid].append(qt)
+                        continue
+                req = planner.request_for(v)
+                ticket = self.submit(req, deadline, priority=priority,
+                                     tenant=tenant)
+                qt.tickets.append(ticket)
+                if not ticket.admitted:
+                    planner.abandon(req)
+                    qt.shed_seeds.append(v)
+                    qt._fill(v, None)
+                    continue
+                self._query_waiters[req.request_id] = [qt]
+                self._inflight_seed[v] = req.request_id
         return qt
 
     def apply_delta(self, edge_inserts: Sequence = (),
@@ -1101,20 +1103,77 @@ class ContinuousGraphServer:
         and, above ``pressure_threshold``, sheds at-risk queued work
         lowest-class-first (:meth:`_shed_pressure`) before cutting.
         """
-        now = self.clock()
-        pressure = self.backlog_bound()
-        if pressure > self.peak_pressure:
-            self.peak_pressure = pressure
-        if pressure > self.pressure_threshold:
-            self._shed_pressure(now, pressure)
-        return self._route(self._dispatch(self._cut_ready(now)))
+        with spans.span("serve.tick"):
+            now = self.clock()
+            with spans.span("serve.cut"):
+                pressure = self.backlog_bound()
+                if pressure > self.peak_pressure:
+                    self.peak_pressure = pressure
+                if pressure > self.pressure_threshold:
+                    self._shed_pressure(now, pressure)
+                ready = self._cut_ready(now)
+            return self._route(self._dispatch(ready))
 
     def drain(self) -> List[GraphResult]:
         """Force-flush: cut everything still queued (partial waves allowed,
         reason ``"drain"``), dispatch in packed order, return the results.
         The queue is empty afterwards."""
-        return self._route(
-            self._dispatch(self._cut_ready(self.clock(), drain=True)))
+        with spans.span("serve.tick"):
+            with spans.span("serve.cut"):
+                ready = self._cut_ready(self.clock(), drain=True)
+            return self._route(self._dispatch(ready))
+
+    def _launch(self, bucket: int, wave: List[QueuedRequest],
+                submesh=None) -> InFlightWave:
+        """Launch one cut wave (``engine.begin_wave``); under a profiler,
+        count its requests and the seconds they waited in the queue."""
+        if spans.enabled():
+            now = self.clock()
+            spans.count("serve.requests_launched", len(wave))
+            spans.count("serve.queue_wait_s",
+                        sum(now - e.arrival for e in wave))
+        return self.engine.begin_wave(bucket, [e.request for e in wave],
+                                      submesh=submesh)
+
+    def _harvest(self, lane: int, wave: List[QueuedRequest], reason: str,
+                 cut_at: float, handle: InFlightWave,
+                 results: List[GraphResult], prev_done: list) -> None:
+        """Block on one launched wave and deliver it into ``results``.
+
+        Its measured wall feeds the bucket EWMA and the pulling lane's
+        (per lane index, or per group size in resize mode), the
+        calibration and the admission floor; the wave is logged and each
+        result stamped and accounted.  ``prev_done`` holds the tick's
+        previous harvest time and is advanced."""
+        with spans.span("serve.harvest", wave=handle.index):
+            wave_results = self.engine.finish_wave(handle)
+            done_at = self.clock()
+            wall = self.engine.bucket_walls[handle.bucket][-1]
+            self._ewma_for(handle.bucket).observe(wall)
+            if self._resize:
+                self._size_wall(handle.pending.lanes).observe(wall)
+            else:
+                self._lane_ewma[lane].observe(wall)
+            self._calib.observe(sum(e.cost for e in wave), wall)
+            self._occupancy.observe(len(wave))
+            # MARGINAL wall-clock for this wave: waves cut in the same
+            # tick dispatch back-to-back, so (done - cut) of a later wave
+            # includes its predecessors' walls and would inflate the
+            # admission floor several-fold at steady load
+            start = (cut_at if prev_done[0] is None
+                     else max(cut_at, prev_done[0]))
+            self._wave_floor.observe(done_at - start)
+            prev_done[0] = done_at
+            self.dispatch_log.append(WaveLog(
+                handle.bucket, len(wave), reason, cut_at, wall, lane,
+                group_size=handle.pending.lanes,
+                classes=self._wave_classes(wave)))
+            self.dispatched += len(wave)
+            for entry, res in zip(wave, wave_results):
+                res.deadline = entry.deadline
+                res.completed_at = done_at
+                self._account_delivery(entry, done_at)
+                results.append(res)
 
     def _dispatch(self, ready: List[tuple]) -> List[GraphResult]:
         """Dispatch the tick's cut waves over the ``n_lanes`` lanes.
@@ -1151,33 +1210,9 @@ class ContinuousGraphServer:
         prev_done = [None]                 # last harvest time THIS tick
 
         def harvest(item) -> None:
-            lane, est, wave, reason, cut_at, handle = item
-            wave_results = self.engine.finish_wave(handle)
+            lane, est, *launched = item
             lane_busy[lane] -= est         # the lane is free again
-            done_at = self.clock()
-            wall = self.engine.bucket_walls[handle.bucket][-1]
-            self._ewma_for(handle.bucket).observe(wall)
-            self._lane_ewma[lane].observe(wall)
-            self._calib.observe(sum(e.cost for e in wave), wall)
-            self._occupancy.observe(len(wave))
-            # MARGINAL wall-clock for this wave: waves cut in the same
-            # tick dispatch back-to-back, so (done - cut) of a later wave
-            # includes its predecessors' walls and would inflate the
-            # admission floor several-fold at steady load
-            start = (cut_at if prev_done[0] is None
-                     else max(cut_at, prev_done[0]))
-            self._wave_floor.observe(done_at - start)
-            prev_done[0] = done_at
-            self.dispatch_log.append(WaveLog(
-                handle.bucket, len(wave), reason, cut_at, wall, lane,
-                group_size=handle.pending.lanes,
-                classes=self._wave_classes(wave)))
-            self.dispatched += len(wave)
-            for entry, res in zip(wave, wave_results):
-                res.deadline = entry.deadline
-                res.completed_at = done_at
-                self._account_delivery(entry, done_at)
-                results.append(res)
+            self._harvest(lane, *launched, results, prev_done)
 
         try:
             for bucket, wave, reason, cut_at in self._pack_order(ready):
@@ -1197,8 +1232,7 @@ class ContinuousGraphServer:
                                           % self.n_lanes))
                 self._next_lane = (lane + 1) % self.n_lanes
                 est = self.estimate(bucket)
-                handle = self.engine.begin_wave(
-                    bucket, [e.request for e in wave])
+                handle = self._launch(bucket, wave)
                 lane_busy[lane] += est
                 in_flight.append((lane, est, wave, reason, cut_at, handle))
         finally:
@@ -1267,30 +1301,7 @@ class ContinuousGraphServer:
         prev_done = [None]                 # last harvest time THIS tick
 
         def harvest(g: int) -> None:
-            wave, reason, cut_at, handle = in_flight.pop(g)
-            wave_results = self.engine.finish_wave(handle)
-            done_at = self.clock()
-            wall = self.engine.bucket_walls[handle.bucket][-1]
-            self._ewma_for(handle.bucket).observe(wall)
-            self._size_wall(handle.pending.lanes).observe(wall)
-            self._calib.observe(sum(e.cost for e in wave), wall)
-            self._occupancy.observe(len(wave))
-            # marginal wall-clock (see _dispatch): don't charge this wave
-            # for predecessors harvested earlier in the same tick
-            start = (cut_at if prev_done[0] is None
-                     else max(cut_at, prev_done[0]))
-            self._wave_floor.observe(done_at - start)
-            prev_done[0] = done_at
-            self.dispatch_log.append(WaveLog(
-                handle.bucket, len(wave), reason, cut_at, wall, g,
-                group_size=handle.pending.lanes,
-                classes=self._wave_classes(wave)))
-            self.dispatched += len(wave)
-            for entry, res in zip(wave, wave_results):
-                res.deadline = entry.deadline
-                res.completed_at = done_at
-                self._account_delivery(entry, done_at)
-                results.append(res)
+            self._harvest(g, *in_flight.pop(g), results, prev_done)
 
         try:
             for i, (bucket, wave, reason, cut_at) in enumerate(packed):
@@ -1301,8 +1312,7 @@ class ContinuousGraphServer:
                 g = assign[i]
                 if g in in_flight:          # one wave per group at a time
                     harvest(g)
-                handle = self.engine.begin_wave(
-                    bucket, [e.request for e in wave], submesh=groups[g])
+                handle = self._launch(bucket, wave, submesh=groups[g])
                 in_flight[g] = (wave, reason, cut_at, handle)
         finally:
             # mirror _dispatch: a begin_wave failure must not abandon

@@ -186,12 +186,28 @@ def test_query_ticket_shed_rows_are_nan():
 
 # -- per-wave gather plumbing -----------------------------------------------
 
-def test_gather_seconds_surfaces_in_report():
+def test_gather_seconds_surfaces_in_report(tmp_path):
+    """The per-wave gather is the ``serve.fill`` span: under a profiler,
+    one per wave, inside the same wave's ``serve.launch``."""
+    import jax
+
+    from repro import spans
+
     mb, _ = _mb("gcn")
-    mb.serve_queries([[3, 7, 11]])
-    rep = mb.engine.last_wave_report
-    assert rep is not None and rep.gather_seconds > 0.0
-    assert mb.report()["last_gather_seconds"] == rep.gather_seconds
+    waves0 = mb.engine.waves
+    with jax.profiler.trace(str(tmp_path)):
+        mb.serve_queries([[3, 7, 11], [20]])
+    recorded, _ = spans.snapshot()
+    fills = [s for s in recorded if s.name == "serve.fill"]
+    assert mb.engine.waves > waves0
+    assert sorted(s.ids["wave"] for s in fills) == list(
+        range(waves0, mb.engine.waves))
+    for fill in fills:
+        launch = recorded[fill.parent]
+        assert launch.name == "serve.launch"
+        assert launch.ids["wave"] == fill.ids["wave"]
+        assert launch.start_ns <= fill.start_ns < fill.end_ns <= launch.end_ns
+    assert "last_gather_seconds" not in mb.report()
 
 
 # -- continuous front door --------------------------------------------------
