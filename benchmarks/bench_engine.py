@@ -7,7 +7,7 @@ Three generations of the same inference:
   reduction step (one eager XLA launch per block pair).  Kept here (not in
   ``core``) purely as the benchmark baseline.
 * ``DynasparseEngine`` -- one cached jit-compiled executor call PER KERNEL
-  (profile -> plan -> ``lax.switch`` dispatch -> fused epilogue in a
+  (profile -> plan -> masked block contraction -> fused epilogue in a
   single XLA program each).
 * ``FusedModelExecutor`` -- the WHOLE model as one jit-compiled program:
   layer l+1's K2P plan chains from layer l's writeback density profile

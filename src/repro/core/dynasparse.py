@@ -1,34 +1,37 @@
-"""The unified Dynasparse executor: profile -> plan -> dispatch in one ``jit``.
+"""The unified Dynasparse executor: profile -> plan -> execute in one ``jit``.
 
 This is the single execution path for the paper's mechanism -- both the
 GNN engine (``core.runtime.DynasparseEngine``) and the LM layers
 (``models.layers``) run every kernel through it.  The whole pipeline --
 
     profile block densities  ->  plan_codes (any strategy, traced)  ->
-    per-task ``lax.switch`` over primitive branches inside a ``lax.scan``
-    task loop  ->  fused epilogue (residual + scale + activation)  ->
+    one batched block contraction that applies the plan as a mask  ->
+    fused epilogue (residual + scale + activation)  ->
     result block-density profile fused at writeback
 
--- is traced once per (shapes, block, strategy, epilogue) signature; at
-runtime ``lax.switch`` executes ONLY the selected branch, so an all-zero
-block pair costs no MACs (SKIP branch), which is real data-dependent work
-elision under XLA's static shapes.  With ``use_kernels=True`` the non-dense
-branches call the Pallas block-sparse kernels, whose clamped-index masked
-loops additionally scale *within-block* cost by tile density (the
+-- is traced once per (shapes, block, strategy, epilogue) signature.  On
+the XLA path every non-SKIP primitive is the same dense block product, so
+the plan only decides which (i, j, k) block products enter the sum: the
+lhs blocks of SKIP steps are selected to zero and all I*J*K products run
+as one ``dot_general`` on the MXU, with no sequential loop over tasks.
+With ``use_kernels=True`` the non-SKIP steps instead call the Pallas
+block-sparse kernels one block pair at a time, in a ``lax.scan`` over
+output tasks with a ``lax.switch`` per reduction step, whose clamped-index
+masked loops additionally scale *within-block* cost by tile density (the
 TPU-granularity analogue of the FPGA's element-granularity skipping; see
 DESIGN.md section 2).
 
 The planner can also be bypassed: pass precomputed ``codes`` (e.g. planned
 from layer l's writeback density profile while layer l executes -- the
-paper's K2P/execution overlap, Section V-B2) and the executor dispatches
+paper's K2P/execution overlap, Section V-B2) and the executor applies
 them verbatim.  The ``out_density`` side output is what feeds that
 next-layer plan: it is computed from the value being written anyway, so XLA
 fuses the counting into the producing kernel (the FPGA's comparator array at
 the Result Buffer port).
 
-The scan-over-tasks structure mirrors Algorithm 8: each scan step is one
-"task" (an output partition); on a real mesh the task loop is sharded over
-chips by ``shard_map`` so chips play the role of Computation Cores.
+Each (i, j) output block is one "task" of Algorithm 8; on a real mesh the
+serving layer shards whole requests over chips by ``shard_map`` so chips
+play the role of Computation Cores.
 """
 from __future__ import annotations
 
@@ -90,16 +93,6 @@ def ell_when(want: jnp.ndarray, x: jnp.ndarray, rmax: int) -> formats.ELLMatrix:
     return jax.lax.cond(want == Format.CSR, _conv, _zero)
 
 
-def _block_tensor(x: jnp.ndarray, bm: int, bn: int) -> jnp.ndarray:
-    """(M, N) -> (Mb, Nb, bm, bn), zero-padding to block multiples."""
-    m, n = x.shape
-    pm, pn = (-m) % bm, (-n) % bn
-    if pm or pn:
-        x = jnp.pad(x, ((0, pm), (0, pn)))
-    mb, nb = x.shape[0] // bm, x.shape[1] // bn
-    return x.reshape(mb, bm, nb, bn).transpose(0, 2, 1, 3)
-
-
 def _blocked_density(xb: jnp.ndarray, m: int, n: int) -> jnp.ndarray:
     """Per-block density of a blocked tensor -- same normalization as
     ``profiler.block_density``, so the traced planner sees the same numbers
@@ -138,18 +131,19 @@ def dynasparse_matmul(
     format_aware: bool = False,
     csr_rmax: int = 64,
 ) -> DynasparseResult:
-    """``x @ y`` with per-(partition pair) primitive dispatch + fused epilogue.
+    """``x @ y`` with per-(partition pair) primitive plan + fused epilogue.
 
     block = (bm, bk, bn): X is partitioned (bm x bk), Y (bk x bn) -- the
-    paper's N1/N2 partitions.  ``strategy`` picks the K2P rule: ``dynamic``
-    runs Algorithm 7 through ``cost_model.select_traced`` (Table IV rule or
-    the TPU tile-density rule); ``s1``/``s2``/``gemm`` are the static
-    baselines (``s1`` needs ``kernel_type``).
+    paper's N1/N2 partitions, an (I, J, K) grid of block products.
+    ``strategy`` picks the K2P rule: ``dynamic`` runs Algorithm 7 through
+    ``cost_model.select_traced`` (Table IV rule or the TPU tile-density
+    rule); ``s1``/``s2``/``gemm`` are the static baselines (``s1`` needs
+    ``kernel_type``).
 
     Planner bypasses (both are how the paper overlaps K2P with execution,
     Section V-B2):
 
-    * ``codes`` -- a precomputed (I, J, K) int32 Primitive grid is dispatched
+    * ``codes`` -- a precomputed (I, J, K) int32 Primitive grid is applied
       verbatim; the in-trace planner does not run.
     * ``dens_x`` / ``dens_y`` -- precomputed operand block densities at the
       CONSUMER granularity ((I, K) for X at (bm, bk) blocks, (K, J) for Y at
@@ -167,12 +161,20 @@ def dynasparse_matmul(
     result at ``out_block`` granularity (defaults to (bm, bn)) for planning
     the next kernel while this one executes.
 
-    ``use_kernels=True`` routes the GEMM/SpDMM/SPMM branches through the
-    Pallas block-sparse kernels (``repro.kernels.ops``) tiled
-    ``tile``/``unroll`` -- tile-granularity zero skipping on top of the
-    block-granularity SKIP; interpret mode off-TPU.  False keeps the XLA
-    dot path.  Value semantics are identical either way (the dispatch
-    NEVER changes the result, only the cost -- see
+    Execution computes ``out[i, j] = sum_k [codes[i, j, k] != SKIP] *
+    X[i, k] @ Y[k, j]``, each product at default dot precision with float32
+    accumulation.  With ``use_kernels=False`` (the XLA path) that sum is one
+    batched block contraction: the lhs blocks of SKIP steps are selected to
+    zero and every (i, j, k) product runs in a single ``dot_general``.  The
+    masked lhs holds J copies of X.  A skipped product adds exactly zero
+    whatever its lhs block holds; one whose rhs block holds inf or NaN adds
+    NaN (0 * inf), where the loop below adds nothing.  ``use_kernels=True``
+    instead runs a ``lax.scan`` over the I*J output tasks with a
+    ``fori_loop`` over K and a ``lax.switch`` per step that routes
+    GEMM/SpDMM/SPMM to the Pallas block-sparse kernels
+    (``repro.kernels.ops``) tiled ``tile``/``unroll`` -- tile-granularity
+    zero skipping on top of the block-granularity SKIP; interpret mode
+    off-TPU.  Planned codes never change the result, only the cost (see
     ``dynasparse_dense_equivalent``).
 
     Format-aware execution (DESIGN.md section 13): with
@@ -182,7 +184,7 @@ def dynasparse_matmul(
     pre-converted ``ell`` view so the fused walk can share one D2S across
     kernels).  When CSR wins AND every row fits ``csr_rmax`` (checked at
     runtime -- the decision is a prediction, the fit is a fact), the whole
-    task loop is replaced by one row-gather SPMM over the on-the-fly
+    block path is replaced by one row-gather SPMM over the on-the-fly
     converted lhs under a ``lax.cond``; the epilogue and writeback profiling
     are shared, so side outputs keep their meaning.  The primitive ``codes``
     are still planned and returned either way (they are the side-output
@@ -192,8 +194,8 @@ def dynasparse_matmul(
     """
     m, n = x.shape[0], y.shape[1]
     bm, bk, bn = block
-    xb = _block_tensor(x, bm, bk)            # (I, K, bm, bk)
-    yb = _block_tensor(y, bk, bn)            # (K, J, bk, bn)
+    xb = formats.tile_view(x, (bm, bk))      # (I, K, bm, bk)
+    yb = formats.tile_view(y, (bk, bn))      # (K, J, bk, bn)
     I, K = xb.shape[:2]
     J = yb.shape[1]
 
@@ -214,28 +216,35 @@ def dynasparse_matmul(
     if residual is not None:
         out_dtype = jnp.promote_types(out_dtype, residual.dtype)
 
-    def _skip(acc, xk, yk):
-        del xk, yk
-        return acc
+    def _contract():
+        # out[i, j] = sum_k [codes[i, j, k] != SKIP] * X[i, k] @ Y[k, j].
+        # ``where`` rather than a multiply, so a non-finite value in a
+        # skipped lhs block cannot leak into the sum.  The mask is spread to
+        # elements on the row-major operands, so XLA fuses it into one
+        # batched matmul.  Blocked (I, K, bm, bk) operands are relaid out
+        # into the TPU's (8, 128) tiles and back, and their 5-D contraction
+        # compiles to convolutions whose dimension order differs between
+        # the per-kernel and the wave programs, breaking bitwise equality.
+        keep = (codes != Primitive.SKIP).transpose(1, 0, 2)  # (J, I, K)
+        mask = jnp.repeat(jnp.repeat(keep, bm, axis=1), bk, axis=2)
+        xm = jnp.where(mask, formats.pad_to_tiles(x, (bm, bk))[None], 0)
+        ym = formats.pad_to_tiles(y, (bk, bn)).reshape(K * bk, J, bn)
+        o = jnp.einsum("jmk,kjn->jmn", xm, ym,
+                       preferred_element_type=jnp.float32)  # (J, M, bn)
+        return o.transpose(1, 0, 2).reshape(I * bm, J * bn)
 
     def _gemm(acc, xk, yk):
-        if use_kernels:
-            return acc + ops.gemm(xk, yk, tile=(tile[0], tile[1], tile[1])
-                                  ).astype(jnp.float32)
-        return acc + jnp.dot(xk, yk, preferred_element_type=jnp.float32)
+        return acc + ops.gemm(xk, yk, tile=(tile[0], tile[1], tile[1])
+                              ).astype(jnp.float32)
 
     def _spdmm(acc, xk, yk):
-        if use_kernels:
-            return acc + ops.spdmm(xk, yk, tile=tile, bn=tile[1]
-                                   ).astype(jnp.float32)
-        return acc + jnp.dot(xk, yk, preferred_element_type=jnp.float32)
+        return acc + ops.spdmm(xk, yk, tile=tile, bn=tile[1]
+                               ).astype(jnp.float32)
 
     def _spmm(acc, xk, yk):
-        if use_kernels:
-            return acc + ops.spmm(xk, yk, tile=tile).astype(jnp.float32)
-        return acc + jnp.dot(xk, yk, preferred_element_type=jnp.float32)
+        return acc + ops.spmm(xk, yk, tile=tile).astype(jnp.float32)
 
-    branches = (_skip, _gemm, _spdmm, _spmm)
+    branches = (lambda acc, xk, yk: acc, _gemm, _spdmm, _spmm)
 
     def task(_, ij):
         i, j = ij // J, ij % J
@@ -251,12 +260,16 @@ def dynasparse_matmul(
 
         acc = jax.lax.fori_loop(
             0, K, red, jnp.zeros((bm, bn), jnp.float32), unroll=unroll)
-        return None, acc.astype(out_dtype)
+        return None, acc
 
     def _block_path():
-        _, blocks = jax.lax.scan(task, None, jnp.arange(I * J))
-        o = blocks.reshape(I, J, bm, bn).transpose(0, 2, 1, 3)
-        return o.reshape(I * bm, J * bn)[:m, :n]
+        if use_kernels:
+            _, blocks = jax.lax.scan(task, None, jnp.arange(I * J))
+            o = blocks.reshape(I, J, bm, bn).transpose(0, 2, 1, 3)
+            o = o.reshape(I * bm, J * bn)
+        else:
+            o = _contract()
+        return o.astype(out_dtype)[:m, :n]
 
     if format_aware and fmt is not None:
         # On-the-fly D2S + row-gather SPMM, under a cond so a DENSE decision

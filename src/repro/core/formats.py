@@ -139,7 +139,8 @@ def coo_to_dense(coo: COOMatrix) -> jnp.ndarray:
 # Dense <-> BlockCSR (tile-level, for the TPU kernels).
 # --------------------------------------------------------------------------
 
-def _pad_to_tiles(x: jnp.ndarray, tile: Tuple[int, int]) -> jnp.ndarray:
+def pad_to_tiles(x: jnp.ndarray, tile: Tuple[int, int]) -> jnp.ndarray:
+    """Zero-pad (M, N) up to multiples of ``tile``."""
     m, n = x.shape
     tm, tn = tile
     pm, pn = (-m) % tm, (-n) % tn
@@ -150,7 +151,7 @@ def _pad_to_tiles(x: jnp.ndarray, tile: Tuple[int, int]) -> jnp.ndarray:
 
 def tile_view(x: jnp.ndarray, tile: Tuple[int, int]) -> jnp.ndarray:
     """(M, N) -> (Mb, Nb, tm, tn) tile tensor (pads to tile multiples)."""
-    x = _pad_to_tiles(x, tile)
+    x = pad_to_tiles(x, tile)
     m, n = x.shape
     tm, tn = tile
     return x.reshape(m // tm, tm, n // tn, tn).transpose(0, 2, 1, 3)

@@ -53,7 +53,7 @@ class ServeEngine:
     ``dynasparse_matmul`` (``models.layers._linear``), giving pruned
     weights / sparse activations per-block K2P dispatch at serve time --
     the same contracts as the GNN engines (strategy fixed to ``dynamic``,
-    ``use_kernels`` off => XLA dot path with SKIP elision).
+    ``use_kernels`` off => one XLA block contraction, SKIP as a mask).
     """
 
     def __init__(self, bundle: ModelBundle, params, *, slots: int = 8,

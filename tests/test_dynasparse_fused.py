@@ -1,5 +1,6 @@
 """Fused-mode dynasparse matmul: value preservation + dispatch codes."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -51,8 +52,6 @@ def test_use_kernels_branches():
 
 
 def test_jit_composability():
-    import jax
-
     @jax.jit
     def f(x, y):
         return dynasparse_matmul(x, y, block=(32, 32, 32),
@@ -62,3 +61,48 @@ def test_jit_composability():
     np.testing.assert_allclose(
         np.asarray(f(x, y)),
         np.asarray(dynasparse_dense_equivalent(x, y)), atol=2e-4, rtol=2e-4)
+
+
+def masked_block_product(x, y, codes, block):
+    """NumPy reference: sum of the block products whose code is not SKIP."""
+    bm, bk, bn = block
+    x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+    out = np.zeros((x.shape[0], y.shape[1]))
+    for i, j, k in zip(*np.nonzero(np.asarray(codes) != Primitive.SKIP)):
+        out[i * bm:(i + 1) * bm, j * bn:(j + 1) * bn] += (
+            x[i * bm:(i + 1) * bm, k * bk:(k + 1) * bk]
+            @ y[k * bk:(k + 1) * bk, j * bn:(j + 1) * bn])
+    return out
+
+
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_verbatim_skip_codes_drop_nonzero_blocks(use_kernels):
+    """SKIP placed on nonzero blocks drops exactly those products, and a
+    non-finite lhs block under SKIP for every output column stays out."""
+    block = (16, 16, 16)
+    rng = np.random.default_rng(11)
+    x = rng.normal(size=(64, 48)).astype(np.float32)  # every block nonzero
+    y = jnp.asarray(rng.normal(size=(48, 32)).astype(np.float32))
+    codes = rng.integers(0, 4, size=(4, 2, 3)).astype(np.int32)
+    codes[0, :, 1] = Primitive.SKIP
+    codes[3, 1, :] = Primitive.SKIP
+    x[:16, 16:32] = np.nan                           # block (0, 1)
+    r = dynasparse_matmul(jnp.asarray(x), y, codes=jnp.asarray(codes),
+                          block=block, use_kernels=use_kernels, tile=(8, 8))
+    want = masked_block_product(x, y, codes, block)
+    np.testing.assert_array_equal(np.asarray(r.codes), codes)
+    np.testing.assert_allclose(np.asarray(r.out), want, atol=2e-4,
+                               rtol=2e-4)
+    assert np.all(np.asarray(r.out)[48:, 16:] == 0)
+
+
+@pytest.mark.parametrize("use_kernels, loops", [(False, False),
+                                                (True, True)])
+def test_task_loop_only_on_kernel_path(use_kernels, loops):
+    """The XLA path is one contraction with no loop; the Pallas path keeps
+    its scan over tasks."""
+    x, y = sparse(64, 48, 0.3), sparse(48, 32, 0.6)
+    text = dynasparse_matmul.lower(x, y, block=(16, 16, 16),
+                                   use_kernels=use_kernels,
+                                   tile=(8, 8)).as_text()
+    assert ("while" in text) == loops

@@ -13,7 +13,9 @@ only the worker that runs these tests loads the TPU library.  The
 persistent compilation cache is off around the compiles: an entry written
 for a described chip cannot be read back without one.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import compiler, profiler, runtime
 from repro.core.compiler import GraphMeta
+from repro.core.dynasparse import dynasparse_matmul
 from repro.data import graphs as graph_data
 from repro.kernels import ops
 from repro.models import gnn
@@ -82,6 +85,9 @@ def test_fused_gcn_cora_full_size_compiles(one_chip):
     mem = compiled.memory_analysis()
     # the program holds its inputs plus temporaries well inside 16 GB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 ** 30
+    # the block contraction's masked lhs is J copies of its operand where
+    # XLA materializes it; at Cora's grids that stays a small multiple of A
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
 
 
 def test_serving_wave_program_compiles(one_chip):
@@ -103,6 +109,39 @@ def test_serving_wave_program_compiles(one_chip):
                           for name, blk in shared_needed)
     fn = ex._build_batch(cm, shared_needed, request_needed)
     fn.lower(shared, shared_counts, batched).compile()
+
+
+def _matmuls(text):
+    """The compiled program's matmuls (the TPU's convolutions), operand
+    names and memory-space marks left out."""
+    return sorted(
+        re.sub(r"%[\w.\-]+|S\(1\)", "", line.split(", metadata=")[0]).strip()
+        for line in text.splitlines() if " convolution(" in line)
+
+
+@pytest.mark.parametrize("n, f_in, f_out", [(256, 256, 16), (256, 1433, 16),
+                                            (128, 500, 128)])
+def test_block_contraction_compiles_alike_in_a_wave(one_chip, n, f_in, f_out):
+    """An Update then an Aggregate, alone and inside a scan over four
+    stacked requests (as a serving wave runs them), compile to the same
+    matmuls, which is what keeps served answers bitwise equal to the
+    per-request engine's on the chip."""
+    mm = functools.partial(dynasparse_matmul, block=(16, 16, 16))
+
+    def model(a, h, w):
+        return mm(a, mm(h, w).out, activation="relu").out
+
+    def wave(a, h, w):
+        return jax.lax.scan(lambda _, ah: (None, model(*ah, w)), None,
+                            (a, h))[1]
+
+    shapes = ((n, n), (n, f_in))
+    w = _shape(one_chip, (f_in, f_out))
+    alone = jax.jit(model).lower(*(_shape(one_chip, s) for s in shapes), w)
+    stacked = jax.jit(wave).lower(
+        *(_shape(one_chip, (4,) + s) for s in shapes), w)
+    want = _matmuls(alone.compile().as_text())
+    assert want and _matmuls(stacked.compile().as_text()) == want
 
 
 KERNELS = {
