@@ -266,6 +266,16 @@ def _gat_oracle(compiled, tensors):
             alpha = ex / np.maximum(ex.sum(axis=1, keepdims=True), 1e-30)
             env[k.out] = np.where(alpha > k.att_threshold, alpha, 0.0)
             continue
+        if k.kernel_type == KernelType.HEAD_COMBINE:
+            heads = [env[name] for name in k.heads_in]
+            out = (sum(heads) / len(heads) if k.head_mean
+                   else np.concatenate(heads, axis=1))
+            if k.epilogue_add is not None:
+                out = out + env[k.epilogue_add]
+            if k.activation_enabled and k.activation == Activation.ELU:
+                out = np.where(out > 0, out, np.expm1(out))
+            env[k.out] = out
+            continue
         if k.kernel_type == KernelType.AGGREGATE and k.lhs == "A":
             x = env["A" if k.agg_op == AggOp.SUM else "A_mean"]
         else:
@@ -311,7 +321,7 @@ def run_gat(*, smoke: bool = False, write_json: bool = True,
     hist = {out: {p.name: int((codes == int(p)).sum())
                   for p in Primitive}
             for out, codes in heads.items()}
-    l1 = sorted(h for h in heads if h in ("G1h1", "H1"))
+    l1 = sorted(h for h in heads if h in ("G1h1", "G1h2"))
     distinct = (len(l1) == 2
                 and not np.array_equal(heads[l1[0]], heads[l1[1]]))
     row = {

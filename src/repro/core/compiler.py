@@ -40,17 +40,31 @@ class GNNModelSpec:
     activation: Activation = Activation.RELU
     sgc_hops: int = 2                # K for SGC
     gin_eps: float = 0.0
-    # GAT only (DESIGN.md §17).  Heads are summed (not concatenated) so
-    # ``layer_dims`` keeps its meaning; the threshold is the post-softmax
-    # cutoff below which an attention weight is dropped to exactly zero,
-    # which is what makes each head's operand density input-dependent.
-    gat_heads: int = 2
+    # GAT only (DESIGN.md §17), as published: ``gat_heads[l - 1]`` heads in
+    # layer l, concatenated in a hidden layer (``layer_dims[l]`` is heads x
+    # head width) and averaged in the output layer (``layer_dims[-1]`` is
+    # the head width); ``gat_skip`` lists the layers that add a projected
+    # skip connection ``H_{l-1} P_l`` before the activation.  The threshold
+    # is the post-softmax cutoff below which an attention weight is dropped
+    # to exactly zero; 0 keeps the exact edge softmax.
+    gat_heads: Tuple[int, ...] = ()
+    gat_skip: Tuple[int, ...] = ()
     att_slope: float = 0.2
-    att_threshold: float = 0.02
+    att_threshold: float = 0.0
 
     @property
     def n_layers(self) -> int:
         return len(self.layer_dims) - 1
+
+    def head_width(self, layer: int) -> int:
+        """Output width of one GAT head of ``layer`` (1-based)."""
+        heads, width = self.gat_heads[layer - 1], self.layer_dims[layer]
+        if layer == self.n_layers:
+            return width
+        if width % heads:
+            raise ValueError(f"layer {layer}: width {width} does not split "
+                             f"into {heads} concatenated heads")
+        return width // heads
 
 
 @dataclasses.dataclass
@@ -118,32 +132,42 @@ def build_computation_graph(spec: GNNModelSpec, meta: GraphMeta) -> ComputationG
             ks.append(_upd(l, f_out, f_out, meta, f"M{l}", f"Wb{l}", f"H{l}",
                            act, act_on=not last))
         elif model == "gat":
-            # Per head h: Z = h W_h (Update); T = edge-softmax over the
-            # adjacency support with per-head scores, thresholded
-            # (Attention); out = T Z (Aggregate).  Heads are summed via the
-            # epilogue-add chain; the last head applies the activation and
-            # writes H{l}.  Each head's T has its own runtime density, so
+            # Per head hd: Z = h W_hd (Update); T = edge softmax over the
+            # adjacency support with the head's scores (Attention); G = T Z
+            # (Aggregate).  Each head's T has its own runtime density, so
             # the fused walk plans a distinct (primitive, format) grid per
-            # head from the propagated writeback profiles (DESIGN.md §17).
-            prev = None
-            for hd in range(1, spec.gat_heads + 1):
+            # head from the propagated writeback profiles.  The heads then
+            # meet in one HEAD_COMBINE kernel: concatenated (hidden layer)
+            # or averaged (output layer), plus the skip projection S = h P
+            # where the layer has one, then the activation (DESIGN.md §17).
+            if len(spec.gat_heads) != L:
+                raise ValueError(f"gat needs one head count per layer, got "
+                                 f"{spec.gat_heads} for {L} layers")
+            fh = spec.head_width(l)
+            heads_out = []
+            for hd in range(1, spec.gat_heads[l - 1] + 1):
                 z, t = f"Z{l}h{hd}", f"T{l}h{hd}"
-                ks.append(_upd(l, f_in, f_out, meta, h, f"Wg{l}h{hd}", z))
+                ks.append(_upd(l, f_in, fh, meta, h, f"Wg{l}h{hd}", z))
                 ks.append(KernelIR(
-                    KernelType.ATTENTION, l, f_out, f_out, meta.n_vertices,
+                    KernelType.ATTENTION, l, fh, fh, meta.n_vertices,
                     meta.n_edges, name=f"l{l}.att.h{hd}", lhs="A", rhs=z,
                     out=t, att_src=f"a_src{l}h{hd}", att_dst=f"a_dst{l}h{hd}",
                     att_slope=spec.att_slope,
                     att_threshold=spec.att_threshold))
-                last_head = hd == spec.gat_heads
-                dst = f"H{l}" if last_head else f"G{l}h{hd}"
                 ks.append(KernelIR(
-                    KernelType.AGGREGATE, l, f_out, f_out, meta.n_vertices,
-                    meta.n_edges, agg_op=AggOp.SUM,
-                    activation=act, activation_enabled=last_head and not last,
-                    name=f"l{l}.agg.h{hd}", lhs=t, rhs=z, out=dst,
-                    epilogue_add=prev))
-                prev = dst
+                    KernelType.AGGREGATE, l, fh, fh, meta.n_vertices,
+                    meta.n_edges, agg_op=AggOp.SUM, name=f"l{l}.agg.h{hd}",
+                    lhs=t, rhs=z, out=f"G{l}h{hd}"))
+                heads_out.append(f"G{l}h{hd}")
+            skip = None
+            if l in spec.gat_skip:
+                skip = f"S{l}"
+                ks.append(_upd(l, f_in, f_out, meta, h, f"P{l}", skip))
+            ks.append(KernelIR(
+                KernelType.HEAD_COMBINE, l, f_out, f_out, meta.n_vertices,
+                meta.n_edges, activation=act, activation_enabled=not last,
+                name=f"l{l}.combine", out=f"H{l}", epilogue_add=skip,
+                heads_in=tuple(heads_out), head_mean=last))
         elif model == "sgc":
             # SGC collapses to A^K H W with no inter-hop nonlinearity;
             # emitted as K Aggregates (first layer only) + one Update.

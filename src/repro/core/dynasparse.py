@@ -102,6 +102,19 @@ def _blocked_density(xb: jnp.ndarray, m: int, n: int) -> jnp.ndarray:
                                         xb.shape[2], xb.shape[3])
 
 
+def _activate(out: jnp.ndarray, activation: str) -> jnp.ndarray:
+    """The writeback epilogue's activation (``ir.Activation`` values)."""
+    if activation == "relu":
+        return jax.nn.relu(out)
+    if activation == "prelu":
+        return jnp.where(out >= 0, out, 0.25 * out)
+    if activation == "elu":
+        return jax.nn.elu(out)
+    if activation != "none":
+        raise ValueError(f"unknown activation {activation!r}")
+    return out
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("strategy", "kernel_type", "epilogue_scale",
@@ -157,7 +170,7 @@ def dynasparse_matmul(
 
     Epilogue (fused at writeback, matching ``KernelIR``):
     ``out += residual * epilogue_scale`` then ``activation``
-    (none/relu/prelu).  ``out_density``/``out_counts`` profile the final
+    (none/relu/prelu/elu).  ``out_density``/``out_counts`` profile the final
     result at ``out_block`` granularity (defaults to (bm, bn)) for planning
     the next kernel while this one executes.
 
@@ -300,12 +313,7 @@ def dynasparse_matmul(
     if residual is not None:
         out = out + (residual if epilogue_scale == 1.0
                      else residual * epilogue_scale)
-    if activation == "relu":
-        out = jax.nn.relu(out)
-    elif activation == "prelu":
-        out = jnp.where(out >= 0, out, 0.25 * out)
-    elif activation != "none":
-        raise ValueError(f"unknown activation {activation!r}")
+    out = _activate(out, activation)
 
     # --- Sparsity Profiler fused at writeback (Section V-B2) ---
     ob = out_block or (bm, bn)
@@ -334,7 +342,7 @@ def attention_adjacency(
     threshold: float = 0.0,
     out_block: Tuple[int, int] = (128, 128),
 ) -> DynasparseResult:
-    """Thresholded masked edge-softmax over the adjacency support (GAT).
+    """Masked edge softmax over the adjacency support (GAT), thresholded.
 
     The one attention implementation BOTH engines execute (DESIGN.md §17)
     -- ``DynasparseEngine`` dispatches it standalone, the fused walk
@@ -355,7 +363,9 @@ def attention_adjacency(
       are dropped to exactly zero.  Rows sum to 1 before thresholding, so
       a head whose attention concentrates keeps few edges and a diffuse
       head keeps many -- per-head, per-input operand density, the thing
-      the K2P planner cannot know until runtime.
+      the K2P planner cannot know until runtime.  ``threshold=0`` (the
+      published model) drops only weights that are exactly zero, so the
+      softmax is exact.
 
     Returns a :class:`DynasparseResult` so the side-output plumbing
     (writeback counts chained into the consumer's planner, report
@@ -399,4 +409,45 @@ def attention_adjacency(
     one = jnp.ones((1, 1), jnp.float32)
     codes = jnp.full((1, 1, 1), Primitive.GEMM, jnp.int32)
     return DynasparseResult(alpha, codes, one, one, out_density, out_counts,
+                            jnp.zeros((), jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("mean", "activation",
+                                             "out_block"))
+def head_combine(
+    heads: Tuple[jnp.ndarray, ...],
+    residual: Optional[jnp.ndarray] = None,
+    *,
+    mean: bool,
+    activation: str = "none",
+    out_block: Tuple[int, int] = (128, 128),
+) -> DynasparseResult:
+    """One GAT layer's heads combined as published (DESIGN.md §17).
+
+    The heads' (n, f) Aggregate outputs are concatenated in head order to
+    (n, heads * f), or averaged when ``mean`` (the output layer); then the
+    skip projection ``residual`` is added and ``activation`` applied, and
+    the result is profiled at writeback like every kernel's, so the next
+    layer plans from it.  ELU keeps zeros and signs, so the combined
+    density is that of the sum, never thinned as ReLU thins it.
+
+    The one implementation both engines execute, which keeps their outputs
+    bitwise equal.  Nothing is planned: ``codes`` is one SKIP step, as the
+    combination performs no block product.
+    """
+    if mean:
+        out = functools.reduce(jnp.add, heads[1:], heads[0]) / len(heads)
+    else:
+        out = jnp.concatenate(heads, axis=1)
+    if residual is not None:
+        out = out + residual
+    out = _activate(out, activation)
+    m, n = out.shape
+    with jax.named_scope("writeback_profile"):
+        out_counts = profiler.block_counts(out, out_block)
+        out_density = profiler.density_from_counts(out_counts, m, n,
+                                                   *out_block)
+    one = jnp.ones((1, 1), jnp.float32)
+    codes = jnp.full((1, 1, 1), Primitive.SKIP, jnp.int32)
+    return DynasparseResult(out, codes, one, one, out_density, out_counts,
                             jnp.zeros((), jnp.int32))

@@ -15,9 +15,13 @@ from typing import Dict, List, Optional, Tuple
 class KernelType(enum.IntEnum):
     AGGREGATE = 0
     UPDATE = 1
-    # element-wise epilogues are folded into the producing kernel (the FPGA
-    # applies activation on the writeback path); kept for IR completeness:
-    ELEMENTWISE = 2
+    # GAT's per-layer combination of its heads' Aggregate outputs (DESIGN.md
+    # §17): concatenated in a hidden layer, averaged in the output layer,
+    # then the skip projection added and the activation applied.  Element-
+    # wise, not a matmul: executed by ``dynasparse.head_combine`` in both
+    # engines.  Every other epilogue is folded into its producing kernel
+    # (the FPGA applies activation on the writeback path).
+    HEAD_COMBINE = 2
     # masked edge-softmax over the adjacency support (GAT, DESIGN.md §17):
     # produces a (|V|, |V|) attention matrix whose sparsity is input- and
     # head-dependent -- the operand whose density the K2P planner cannot
@@ -37,6 +41,7 @@ class Activation(enum.Enum):
     NONE = "none"
     RELU = "relu"
     PRELU = "prelu"
+    ELU = "elu"
 
 
 @dataclasses.dataclass
@@ -84,6 +89,10 @@ class KernelIR:
     att_dst: Optional[str] = None
     att_slope: float = 0.2
     att_threshold: float = 0.0
+    # HEAD_COMBINE kernels only: the heads' Aggregate outputs in head order
+    # and whether they are averaged (the output layer) or concatenated.
+    heads_in: Tuple[str, ...] = ()
+    head_mean: bool = False
     scheme: ExecutionScheme = dataclasses.field(default_factory=ExecutionScheme)
 
     @property
@@ -160,13 +169,13 @@ class ComputationGraph:
         produced: Dict[str, int] = {}
         out = []
         for i, k in enumerate(self.kernels):
-            for dep in (k.lhs, k.rhs, k.epilogue_add):
+            for dep in (k.lhs, k.rhs, k.epilogue_add) + k.heads_in:
                 if dep in produced:
                     out.append((produced[dep], i))
             produced[k.out] = i
         return out
 
-    def operand_flows(self) -> List[Tuple[OperandFlow, OperandFlow]]:
+    def operand_flows(self) -> List[Optional[Tuple[OperandFlow, OperandFlow]]]:
         """Per-kernel (lhs_flow, rhs_flow): the density-propagation wiring.
 
         Requires partitioning to have run (``scheme.n1``/``n2`` set).  For a
@@ -177,11 +186,16 @@ class ComputationGraph:
         asserted here so a future scheme change fails loudly instead of
         silently mis-planning.  Feature operands pool rows only
         (``pool_cols == 1``); the GAT attention matrix consumed at
-        (N1, N1) pools both axes.
+        (N1, N1) pools both axes.  A HEAD_COMBINE kernel plans nothing, so
+        its entry is None.
         """
         produced: Dict[str, int] = {}
-        flows: List[Tuple[OperandFlow, OperandFlow]] = []
+        flows: List[Optional[Tuple[OperandFlow, OperandFlow]]] = []
         for i, k in enumerate(self.kernels):
+            if k.kernel_type == KernelType.HEAD_COMBINE:
+                flows.append(None)
+                produced[k.out] = i
+                continue
             bm, bk, bn = k.block_dims
             n2 = k.scheme.n2
             pair = []

@@ -46,7 +46,7 @@ from repro.core import formats as _formats
 from repro.distributed import sharding as dist_sharding
 from repro.core.compiler import CompiledModel
 from repro.core.dynasparse import (DynasparseResult, attention_adjacency,
-                                   dynasparse_matmul, ell_when)
+                                   dynasparse_matmul, ell_when, head_combine)
 from repro.core.ir import Activation, AggOp, KernelIR, KernelType
 from repro.core.perf_model import FPGACostModel, Format
 from repro.core.profiler import SparsityStats
@@ -155,6 +155,14 @@ class PendingWave:
     lanes: int
     wave_slots: int
     launched_at: float
+
+
+def _side(res: DynasparseResult, counts: bool = False) -> tuple:
+    """A kernel's side outputs from the fused program: (codes, dens_x,
+    dens_y, out_density, fmt), and its writeback counts last where
+    ``counts`` (the attention kernels, whose kept entries are counted)."""
+    side = (res.codes, res.dens_x, res.dens_y, res.out_density, res.fmt)
+    return side + (res.out_counts,) if counts else side
 
 
 def _k2p_model_seconds(num_decisions: int) -> float:
@@ -313,6 +321,17 @@ def _agg_lhs_name(k: KernelIR) -> str:
     return name
 
 
+def _combine(k: KernelIR, env: Dict[str, jnp.ndarray]) -> DynasparseResult:
+    """A HEAD_COMBINE kernel on the tensors of ``env`` (both engines)."""
+    n2 = k.scheme.n2
+    return head_combine(
+        tuple(env[name] for name in k.heads_in),
+        env[k.epilogue_add] if k.epilogue_add is not None else None,
+        mean=k.head_mean,
+        activation=k.activation.value if k.activation_enabled else "none",
+        out_block=(n2, n2))
+
+
 def _bookkeep_kernel(k: KernelIR, codes, dens_x, dens_y, n_cc: int, model
                      ) -> KernelReport:
     """Host bookkeeping from the planner's codes (the MicroBlaze's role):
@@ -460,6 +479,15 @@ class DynasparseEngine:
     # -- one kernel ---------------------------------------------------------
     def _run_kernel(self, k: KernelIR, env: Dict[str, jnp.ndarray],
                     n_cc: int) -> Tuple[jnp.ndarray, KernelReport]:
+        if k.kernel_type == KernelType.HEAD_COMBINE:
+            # elementwise, shared with the fused walk like the attention
+            res = _combine(k, env)
+            self.profiled_densities[k.out] = res.out_density
+            if self.keep_codes:
+                self.planned_codes[k.out] = np.asarray(res.codes)
+                self.planned_formats[k.out] = int(res.fmt)
+            return res.out, _bookkeep_kernel(k, res.codes, res.dens_x,
+                                             res.dens_y, n_cc, self.model)
         if k.kernel_type == KernelType.AGGREGATE:
             x = env[_agg_lhs_name(k)]
         else:
@@ -498,6 +526,22 @@ class DynasparseEngine:
         rep = _bookkeep_kernel(k, res.codes, res.dens_x, res.dens_y,
                                n_cc, self.model)
         return res.out, rep
+
+
+def _count_attention(compiled: CompiledModel, needed, in_counts,
+                     sides) -> None:
+    """Counters of one inference's attention kernels, while a profiler
+    runs: ``attention.heads`` kernels, ``attention.support`` entries of
+    the adjacency support summed over them, ``attention.kept`` entries
+    with a weight above zero (from their writeback counts)."""
+    flows = compiled.graph.operand_flows()
+    for k, pair, side in zip(compiled.graph.topo_order(), flows, sides):
+        if k.kernel_type != KernelType.ATTENTION:
+            continue
+        support = in_counts[needed.index((pair[0].source, pair[0].block))]
+        spans.count("attention.heads", 1)
+        spans.count("attention.support", int(np.sum(np.asarray(support))))
+        spans.count("attention.kept", int(np.sum(np.asarray(side[5]))))
 
 
 # ---------------------------------------------------------------------------
@@ -619,20 +663,23 @@ class FusedModelExecutor:
             (k.name, k.kernel_type, k.block_dims, k.scheme.n2, k.lhs, k.rhs,
              k.out, k.agg_op.value, k.epilogue_add, k.epilogue_scale,
              k.activation.value if k.activation_enabled else "none",
-             k.att_src, k.att_dst, k.att_slope, k.att_threshold)
+             k.att_src, k.att_dst, k.att_slope, k.att_threshold,
+             k.heads_in, k.head_mean)
             for k in compiled.graph.topo_order())
         return (ks, self._tensor_sig(tensors))
 
     @staticmethod
     def _resolved_flows(compiled: CompiledModel):
         """Per-kernel (lhs, rhs) OperandFlows with Aggregate lhs rebound to
-        its env name ("A"/"A_mean"; the IR names it "A")."""
+        its env name ("A"/"A_mean"; the IR names it "A"); None for a
+        HEAD_COMBINE kernel, which plans nothing."""
         out = []
-        for k, (fx, fy) in zip(compiled.graph.topo_order(),
-                               compiled.graph.operand_flows()):
+        for k, pair in zip(compiled.graph.topo_order(),
+                           compiled.graph.operand_flows()):
             if k.kernel_type == KernelType.AGGREGATE:
-                fx = dataclasses.replace(fx, source=_agg_lhs_name(k))
-            out.append((fx, fy))
+                fx, fy = pair
+                pair = (dataclasses.replace(fx, source=_agg_lhs_name(k)), fy)
+            out.append(pair)
         return out
 
     @staticmethod
@@ -640,8 +687,8 @@ class FusedModelExecutor:
         """Ordered unique (env name, granularity) of every graph-input
         profile the program consumes (profiled host-side, passed in)."""
         seen: List[tuple] = []
-        for fx, fy in flows:
-            for f in (fx, fy):
+        for pair in flows:
+            for f in pair or ():
                 key = (f.source, f.block)
                 if f.producer is None and key not in seen:
                     seen.append(key)
@@ -653,7 +700,7 @@ class FusedModelExecutor:
         programs): walk the topo-ordered kernels, planning each from
         ``profiles`` (graph inputs) or the producer's chained writeback
         counts.  Mutates ``env`` with every kernel's output and returns the
-        per-kernel (codes, dens_x, dens_y, out_density, fmt) side outputs.
+        per-kernel side outputs (``_side``).
 
         Format sharing: when two kernels read the same source tensor (both
         aggregates of a 2-layer GCN read "A"), the fused walk converts it
@@ -668,11 +715,20 @@ class FusedModelExecutor:
         # (source name, shape) -> (want so far, shared ELL view)
         ell_env: Dict[tuple, tuple] = {}
         sides = []
-        for k, (fx, fy) in zip(kernels, flows):
+        for k, pair in zip(kernels, flows):
             # stable names for the device trace: the kernel (aggregate,
-            # update or attention), and inside it k2p_plan, d2s and
-            # dynasparse_matmul's writeback_profile
+            # update, attention or head_combine), and inside it k2p_plan,
+            # d2s and the writeback_profile
             with jax.named_scope(k.kernel_type.name.lower()):
+                if k.kernel_type == KernelType.HEAD_COMBINE:
+                    res = _combine(k, env)
+                    n2c = k.scheme.n2
+                    env[k.out] = res.out
+                    counts_env[k.out] = profiler.BlockProfile(
+                        res.out_counts, res.out.shape, (n2c, n2c))
+                    sides.append(_side(res))
+                    continue
+                fx, fy = pair
                 x, y = env[fx.source], env[fy.source]
                 if k.kernel_type == KernelType.ATTENTION:
                     # masked edge-softmax (GAT): no K2P planning of its own
@@ -688,8 +744,7 @@ class FusedModelExecutor:
                     env[k.out] = res.out
                     counts_env[k.out] = profiler.BlockProfile(
                         res.out_counts, res.out.shape, (n2a, n2a))
-                    sides.append((res.codes, res.dens_x, res.dens_y,
-                                  res.out_density, res.fmt))
+                    sides.append(_side(res, counts=True))
                     continue
                 prof_x, prof_y = (
                     counts_env[f.source].pool_rows(f.pool_rows)
@@ -744,8 +799,7 @@ class FusedModelExecutor:
                 env[k.out] = res.out
                 counts_env[k.out] = profiler.BlockProfile(
                     res.out_counts, res.out.shape, (n2, n2))
-                sides.append((res.codes, res.dens_x, res.dens_y,
-                              res.out_density, res.fmt))
+                sides.append(_side(res))
         return sides
 
     def _build(self, compiled: CompiledModel) -> tuple:
@@ -899,10 +953,11 @@ class FusedModelExecutor:
             reports = []
             if self.collect_report:
                 reports = [
-                    _bookkeep_kernel(k, codes, dens_x, dens_y, n_cc,
+                    _bookkeep_kernel(k, side[0], side[1], side[2], n_cc,
                                      self.model)
-                    for k, (codes, dens_x, dens_y, _, _fmt) in
-                    zip(compiled.graph.topo_order(), sides)]
+                    for k, side in zip(compiled.graph.topo_order(), sides)]
+            if spans.enabled():
+                _count_attention(compiled, needed, in_counts, sides)
         return outs, InferenceReport(reports, self.strategy,
                                      fused_wall_seconds=wall)
 
@@ -1003,11 +1058,10 @@ class FusedModelExecutor:
             reports = []
             if self.collect_report:
                 for b in range(pending.wave_slots):
-                    for k, (codes, dens_x, dens_y, _, _fmt) in zip(topo,
-                                                                  sides):
+                    for k, side in zip(topo, sides):
                         rep = _bookkeep_kernel(
-                            k, codes[b], dens_x[b], dens_y[b], pending.n_cc,
-                            self.model)
+                            k, side[0][b], side[1][b], side[2][b],
+                            pending.n_cc, self.model)
                         rep.name = f"{k.name}[{b}]"
                         reports.append(rep)
         return outs, InferenceReport(reports, self.strategy,

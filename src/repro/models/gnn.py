@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.core import compiler, runtime
 from repro.core.compiler import CompiledModel, GNNModelSpec, GraphMeta
-from repro.core.ir import AggOp, KernelType
+from repro.core.ir import Activation, AggOp, KernelType
 from repro.core.profiler import SparsityStats
 from repro.data import graphs as graph_data
 
@@ -28,9 +28,14 @@ GNN_MODELS = ("gcn", "sage", "gin", "sgc", "gat")
 
 def make_model_spec(model: str, f_in: int, hidden: int, n_classes: int
                     ) -> GNNModelSpec:
-    """The paper's 2-layer models (Section VIII-A)."""
+    """The paper's 2-layer models (Section VIII-A).  ``gat`` is the
+    published architecture at these widths: two ELU heads of ``hidden / 2``
+    concatenated, then two heads of ``n_classes`` averaged."""
     agg = AggOp.MEAN if model == "sage" else AggOp.SUM
     dims = [f_in, n_classes] if model == "sgc" else [f_in, hidden, n_classes]
+    if model == "gat":
+        return GNNModelSpec(model, dims, activation=Activation.ELU,
+                            gat_heads=(2, 2))
     return GNNModelSpec(model, dims, agg_op=agg)
 
 
@@ -62,6 +67,13 @@ def _glorot_pruned(kernels, *, seed: int, density: float
 def _ref_dot(x, y):
     return jnp.dot(x, y, precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)
+
+
+def _ref_activation(spec: GNNModelSpec):
+    return {Activation.RELU: jax.nn.relu, Activation.ELU: jax.nn.elu,
+            Activation.NONE: lambda v: v,
+            Activation.PRELU: lambda v: jnp.where(v >= 0, v, 0.25 * v),
+            }[spec.activation]
 
 
 def _ref_attention(a, z, att_src, att_dst, slope, threshold):
@@ -96,7 +108,7 @@ def reference_forward(spec: GNNModelSpec, tensors: Dict[str, jnp.ndarray]
     relu = jax.nn.relu
     n_layers = spec.n_layers
     for l in range(1, n_layers + 1):
-        act = (lambda v: v) if l == n_layers else relu
+        act = (lambda v: v) if l == n_layers else _ref_activation(spec)
         if model == "gcn":
             h = act(_ref_dot(a, _ref_dot(h, t[f"W{l}"])))
         elif model == "sage":
@@ -111,13 +123,19 @@ def reference_forward(spec: GNNModelSpec, tensors: Dict[str, jnp.ndarray]
                     h = _ref_dot(a, h)
             h = act(_ref_dot(h, t[f"W{l}"]))
         elif model == "gat":
-            out = 0.0
-            for hd in range(1, spec.gat_heads + 1):
+            # heads concatenated in a hidden layer, averaged in the output
+            # layer; the skip projection joins before the activation
+            heads = []
+            for hd in range(1, spec.gat_heads[l - 1] + 1):
                 z = _ref_dot(h, t[f"Wg{l}h{hd}"])
                 alpha = _ref_attention(
                     a, z, t[f"a_src{l}h{hd}"], t[f"a_dst{l}h{hd}"],
                     spec.att_slope, spec.att_threshold)
-                out = out + _ref_dot(alpha, z)
+                heads.append(_ref_dot(alpha, z))
+            out = (sum(heads[1:], heads[0]) / len(heads) if l == n_layers
+                   else jnp.concatenate(heads, axis=1))
+            if l in spec.gat_skip:
+                out = out + _ref_dot(h, t[f"P{l}"])
             h = act(out)
         else:
             raise ValueError(f"unknown GNN model {spec.model!r}")

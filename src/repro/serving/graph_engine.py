@@ -349,7 +349,7 @@ class GraphServeEngine:
             self._compiled[bucket] = cm
             flows = runtime.FusedModelExecutor._resolved_flows(cm)
             self._input_names[bucket] = sorted(
-                {f.source for pair in flows for f in pair
+                {f.source for pair in flows for f in pair or ()
                  if f.producer is None and f.source not in self.weights})
         return cm
 

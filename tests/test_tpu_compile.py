@@ -3,9 +3,10 @@
 The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, SMEM or VMEM overflow, programs that do not fit the
 device.  These tests lower and compile for one chip of a described
-``v5e:2x2`` topology -- the fused gcn program on Cora at full size, one
-serving wave program, and every Pallas kernel with ``interpret=False`` --
-and check that each kernel lands in the program as a ``tpu_custom_call``.
+``v5e:2x2`` topology -- the fused gcn program on Cora and the published
+GAT on a PPI test graph at full size, one serving wave program, and every
+Pallas kernel with ``interpret=False`` -- and check that each kernel lands
+in the program as a ``tpu_custom_call``.
 Nothing runs, so nothing here says anything about results or times.
 
 The topology is described inside a module fixture (never at import), so
@@ -25,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import compiler, profiler, runtime
 from repro.core.compiler import GraphMeta
 from repro.core.dynasparse import dynasparse_matmul
+from repro.core.ir import Activation
 from repro.data import graphs as graph_data
 from repro.kernels import ops
 from repro.models import gnn
@@ -88,6 +90,33 @@ def test_fused_gcn_cora_full_size_compiles(one_chip):
     # the block contraction's masked lhs is J copies of its operand where
     # XLA materializes it; at Cora's grids that stays a small multiple of A
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_fused_gat_ppi_full_size_compiles(one_chip):
+    """Published GAT on one PPI test graph (2,762 vertices; widths 50 ->
+    4 x 256 -> 4 x 256 + skip -> 6 x 121 averaged), the program of the
+    benchmark's ``gat-ppi.whole`` cell, at the serving defaults."""
+    spec = compiler.GNNModelSpec(
+        "gat", [50, 1024, 1024, 121], activation=Activation.ELU,
+        gat_heads=(4, 4, 6), gat_skip=(2,))
+    n = 2762
+    cm = compiler.compile_model(spec, GraphMeta("ppi", n, 39711, 50),
+                                n_cc=7, align=16, on_chip_bytes=256 * 1024)
+    weights = {k: w.shape for k, w in gnn.init_spec_weights(spec).items()}
+    shapes = {"A": (n, n), "A_mean": (n, n), "H0": (n, 50), **weights}
+    tensors = {k: _shape(one_chip, s) for k, s in shapes.items()}
+    ex = runtime.FusedModelExecutor(strategy="dynamic")
+    fn, needed = ex._program(cm, tensors)
+    counts = tuple(_counts(one_chip, shapes[name], blk)
+                   for name, blk in needed)
+    compiled = fn.lower(tensors, counts).compile()
+    mem = compiled.memory_analysis()
+    # the 14 heads' dense (n, n) attention and masked aggregation
+    # operands stay a few hundred MB of the 16 GB
+    assert mem.temp_size_in_bytes < 512 * 2 ** 20
+    text = compiled.as_text()
+    for scope in ("attention", "head_combine", "aggregate", "update"):
+        assert f"/{scope}/" in text, scope
 
 
 def test_serving_wave_program_compiles(one_chip):
