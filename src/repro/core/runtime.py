@@ -33,7 +33,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from collections.abc import Mapping
+from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -76,7 +77,10 @@ class KernelReport:
 
 @dataclasses.dataclass
 class InferenceReport:
-    kernels: List[KernelReport]
+    # the per-kernel rows, or a function that builds them on the first read
+    # of ``kernels`` (the fused program defers its modelled bookkeeping
+    # until a caller asks for it)
+    rows: Union[List[KernelReport], Callable[[], List[KernelReport]]]
     strategy: str
     # set by the fused whole-model executor: the single program's wall time
     # (per-kernel walls are unobservable inside one XLA program).
@@ -91,6 +95,16 @@ class InferenceReport:
     # lane count the wave was dispatched over (1 when unsharded): the size
     # of the ``cores`` mesh axis run_batch sharded the request scan across.
     wave_lanes: int = 1
+    # (n_kernels, 4) int32 primitive counts [SKIP, GEMM, SPDMM, SPMM] that
+    # the fused program counted on the device; ``histogram`` sums them
+    # without building the rows.
+    counts: Optional[np.ndarray] = None
+
+    @property
+    def kernels(self) -> List[KernelReport]:
+        if callable(self.rows):
+            self.rows = self.rows()
+        return self.rows
 
     @property
     def total_cycles(self) -> float:
@@ -135,6 +149,8 @@ class InferenceReport:
 
     @property
     def histogram(self) -> np.ndarray:
+        if self.counts is not None:
+            return self.counts.sum(axis=0, dtype=np.int64)
         return np.sum([k.histogram for k in self.kernels], axis=0)
 
 
@@ -163,6 +179,86 @@ def _side(res: DynasparseResult, counts: bool = False) -> tuple:
     ``counts`` (the attention kernels, whose kept entries are counted)."""
     side = (res.codes, res.dens_x, res.dens_y, res.out_density, res.fmt)
     return side + (res.out_counts,) if counts else side
+
+
+def _pack_report(kernels, flows, sides, profiles) -> tuple:
+    """The single-inference program's report as a few packed arrays
+    (traced): ``((hist, *buffers), layout)``.
+
+    * ``hist`` -- (n_kernels + 1, 4) int32.  Row i is kernel i's primitive
+      counts [SKIP, GEMM, SPDMM, SPMM], ``np.bincount`` of its codes; the
+      last row holds the attention kernels' support entries and kept
+      entries (their writeback counts), each summed over the heads.
+    * ``buffers`` -- one flat array per dtype holding every kernel's
+      ``_side`` fields (codes, dens_x, dens_y, out_density, fmt).
+    * ``layout`` -- static, per kernel and field: (buffer, start, stop,
+      shape) of that field in ``buffers``.
+    """
+    prims = jnp.arange(4, dtype=jnp.int32)
+    rows = [jnp.sum(side[0].reshape(-1, 1) == prims, axis=0, dtype=jnp.int32)
+            for side in sides]
+    # the heads' sums reduced once: a chain of scalar adds costs one
+    # device op per head
+    att = [jnp.stack([jnp.sum(profiles[(pair[0].source, pair[0].block)]
+                              .counts, dtype=jnp.int32),
+                      jnp.sum(side[5], dtype=jnp.int32)])
+           for k, pair, side in zip(kernels, flows, sides)
+           if k.kernel_type == KernelType.ATTENTION]
+    att = (jnp.sum(jnp.stack(att), axis=0, dtype=jnp.int32) if att
+           else jnp.zeros(2, jnp.int32))
+    hist = jnp.stack(rows + [jnp.pad(att, (0, 2))])
+
+    parts: Dict[np.dtype, list] = {}      # dtype -> flat leaves, in order
+    sizes: Dict[np.dtype, int] = {}
+    layout = []
+    for side in sides:
+        slots = []
+        for leaf in side[:5]:
+            leaf = jnp.asarray(leaf)
+            dtype = np.dtype(leaf.dtype)
+            start = sizes.get(dtype, 0)
+            parts.setdefault(dtype, []).append(leaf.reshape(-1))
+            sizes[dtype] = start + leaf.size
+            slots.append((list(parts).index(dtype), start, sizes[dtype],
+                          tuple(leaf.shape)))
+        layout.append(tuple(slots))
+    buffers = tuple(jnp.concatenate(p) for p in parts.values())
+    return (hist,) + buffers, tuple(layout)
+
+
+class _PackedReport:
+    """One inference's packed side outputs, left on the device until a
+    field is first read, then copied to the host in one ``device_get``."""
+
+    def __init__(self, buffers: tuple, layout: tuple):
+        self.buffers, self.layout = buffers, layout
+        self._host = None
+
+    def field(self, kernel: int, field: int) -> np.ndarray:
+        """Field ``field`` of ``_side`` of the ``kernel``-th kernel."""
+        if self._host is None:
+            spans.count("runtime.readbacks", 1)
+            self._host = jax.device_get(self.buffers)
+        buf, start, stop, shape = self.layout[kernel][field]
+        return self._host[buf][start:stop].reshape(shape)
+
+
+class _PackedField(Mapping):
+    """``{kernel output: one field of its side outputs}`` over a
+    :class:`_PackedReport`, read on access."""
+
+    def __init__(self, packed: _PackedReport, outs: List[str], field: int):
+        self._packed, self._field = packed, field
+        self._index = {out: i for i, out in enumerate(outs)}
+
+    def __getitem__(self, out: str) -> np.ndarray:
+        return self._packed.field(self._index[out], self._field)
+
+    def __iter__(self):
+        return iter(self._index)
+
+    def __len__(self) -> int:
+        return len(self._index)
 
 
 def _k2p_model_seconds(num_decisions: int) -> float:
@@ -528,20 +624,17 @@ class DynasparseEngine:
         return res.out, rep
 
 
-def _count_attention(compiled: CompiledModel, needed, in_counts,
-                     sides) -> None:
+def _count_attention(kernels, support: int, kept: int) -> None:
     """Counters of one inference's attention kernels, while a profiler
     runs: ``attention.heads`` kernels, ``attention.support`` entries of
     the adjacency support summed over them, ``attention.kept`` entries
-    with a weight above zero (from their writeback counts)."""
-    flows = compiled.graph.operand_flows()
-    for k, pair, side in zip(compiled.graph.topo_order(), flows, sides):
-        if k.kernel_type != KernelType.ATTENTION:
-            continue
-        support = in_counts[needed.index((pair[0].source, pair[0].block))]
-        spans.count("attention.heads", 1)
-        spans.count("attention.support", int(np.sum(np.asarray(support))))
-        spans.count("attention.kept", int(np.sum(np.asarray(side[5]))))
+    with a weight above zero (from their writeback counts); the sums are
+    the last row of the program's packed histogram."""
+    heads = sum(k.kernel_type == KernelType.ATTENTION for k in kernels)
+    if heads:
+        spans.count("attention.heads", heads)
+        spans.count("attention.support", support)
+        spans.count("attention.kept", kept)
 
 
 # ---------------------------------------------------------------------------
@@ -586,9 +679,13 @@ class FusedModelExecutor:
     (per-kernel wall clocks, ``profiled_densities`` inspection between
     launches); this executor is the serving path.  Both report the same
     ``InferenceReport`` bookkeeping -- histograms, Alg. 8 makespan,
-    modeled K2P time -- derived from the planner's codes, which the fused
-    program returns as side outputs; ``collect_report=False`` skips that
-    host work wholesale for latency-critical serving.
+    modeled K2P time -- derived from the planner's codes.  The
+    single-inference program counts each kernel's primitives itself and
+    packs every kernel's codes and densities into one buffer per dtype, so
+    ``run`` copies only the small histogram to the host and builds the
+    per-kernel rows on their first read.  ``collect_report=False`` drops
+    the report altogether (the wave path still returns per-slot side
+    outputs, which it then leaves on the device).
 
     ``run`` mirrors ``DynasparseEngine.run``'s contract (an env dict
     containing the final output plus an ``InferenceReport``), so model
@@ -625,11 +722,11 @@ class FusedModelExecutor:
         # reading the same source tensor (see _trace_kernels).
         self.format_aware = format_aware
         self.csr_rmax = csr_rmax
-        # serving knob: False skips ALL per-kernel host bookkeeping --
-        # no device->host transfer of the (I, J, K) code grids (tens of MB
-        # per kernel at NELL scale), no O(I*J*K) cost prediction, no Alg. 8
-        # scheduling.  run() then returns a report with no kernel entries,
-        # only the fused wall clock.
+        # serving knob: False skips the report -- no histogram readback
+        # (except for the counters while a profiler runs), no per-kernel
+        # rows, so no O(I*J*K) cost prediction and no Alg. 8 scheduling
+        # even when asked for; finish_batch then copies no code grids.  The
+        # report holds no kernel entries, only the fused wall clock.
         self.collect_report = collect_report
         # one jitted whole-model program per (model structure, tensor
         # signature); cache hits re-launch without re-tracing.
@@ -644,9 +741,10 @@ class FusedModelExecutor:
         # incremented inside the traced function: counts actual traces, not
         # launches (the one-jitted-call-per-inference contract is tested).
         self.trace_count = 0
-        self.profiled_densities: Dict[str, jnp.ndarray] = {}
-        self.planned_codes: Dict[str, np.ndarray] = {}
-        self.planned_formats: Dict[str, np.ndarray] = {}
+        # after ``run``: views of its packed report, copied on first read
+        self.profiled_densities: Mapping[str, np.ndarray] = {}
+        self.planned_codes: Mapping[str, np.ndarray] = {}
+        self.planned_formats: Mapping[str, np.ndarray] = {}
 
     # -- program construction ----------------------------------------------
     @staticmethod
@@ -803,10 +901,14 @@ class FusedModelExecutor:
         return sides
 
     def _build(self, compiled: CompiledModel) -> tuple:
+        """``(fn, needed, layout)``: the jitted program, the graph-input
+        profiles it takes, and the layout of its packed report
+        (``_pack_report``), filled in when ``fn`` is traced."""
         kernels = compiled.graph.topo_order()
         flows = self._resolved_flows(compiled)
         needed = self._needed_inputs(flows)
         final = kernels[-1].out
+        layout = []
 
         def fused(tensors, in_counts):
             self.trace_count += 1          # runs at trace time only
@@ -816,12 +918,15 @@ class FusedModelExecutor:
                     counts, tuple(env[name].shape), blk)
                 for (name, blk), counts in zip(needed, in_counts)}
             sides = self._trace_kernels(kernels, flows, env, profiles)
+            with jax.named_scope("report"):
+                packed, layout[:] = _pack_report(kernels, flows, sides,
+                                                 profiles)
             outs = (dict(env) if self.keep_intermediates
                     else {final: env[final]})
-            return outs, sides
+            return outs, packed
 
         fn = jax.jit(fused, donate_argnums=(0,) if self.donate else ())
-        return fn, needed
+        return fn, needed, layout
 
     def _build_batch(self, compiled: CompiledModel, shared_needed: tuple,
                      request_needed: tuple, lanes: Optional[int] = None):
@@ -895,6 +1000,11 @@ class FusedModelExecutor:
 
     def _program(self, compiled: CompiledModel,
                  tensors: Dict[str, jnp.ndarray]) -> tuple:
+        """``(fn, needed)`` of the single-inference program."""
+        return self._program_entry(compiled, tensors)[:2]
+
+    def _program_entry(self, compiled: CompiledModel,
+                       tensors: Dict[str, jnp.ndarray]) -> tuple:
         key = self._signature(compiled, tensors)
         entry = self._programs.get(key)
         if entry is not None:
@@ -928,38 +1038,51 @@ class FusedModelExecutor:
         intermediates too iff ``keep_intermediates=True``) and ``report``
         carries the same per-kernel bookkeeping as the per-kernel engine,
         plus ``fused_wall_seconds`` (the single program's wall clock).
+
+        The program returns its report packed (``_pack_report``); the
+        report copies only the primitive histogram to the host.  The
+        per-kernel rows, ``profiled_densities`` and (with ``keep_codes``)
+        ``planned_codes``/``planned_formats`` read the packed buffers on
+        first access, which copies them in one more transfer.
         """
         n_cc = self.n_cc or compiled.partition.n_cc
         with spans.span("runtime.dispatch"):
-            fn, needed = self._program(compiled, tensors)
+            fn, needed, layout = self._program_entry(compiled, tensors)
             in_counts = self._input_counts(needed, tensors)
             t0 = time.perf_counter()
-            outs, sides = fn(tensors, in_counts)
+            outs, (hist, *buffers) = fn(tensors, in_counts)
         with spans.span("runtime.wait"):
-            jax.block_until_ready((outs, sides))
+            jax.block_until_ready((outs, hist, buffers))
         wall = time.perf_counter() - t0
 
         with spans.span("runtime.report"):
-            self.profiled_densities = {
-                k.out: side[3]
-                for k, side in zip(compiled.graph.topo_order(), sides)}
+            kernels = compiled.graph.topo_order()
+            packed = _PackedReport(tuple(buffers), layout)
+            names = [k.out for k in kernels]
+            self.profiled_densities = _PackedField(packed, names, 3)
             if self.keep_codes:
-                self.planned_codes = {
-                    k.out: np.asarray(side[0])
-                    for k, side in zip(compiled.graph.topo_order(), sides)}
-                self.planned_formats = {
-                    k.out: np.asarray(side[4])
-                    for k, side in zip(compiled.graph.topo_order(), sides)}
-            reports = []
+                self.planned_codes = _PackedField(packed, names, 0)
+                self.planned_formats = _PackedField(packed, names, 4)
+            rows, counts = [], None
+            if self.collect_report or spans.enabled():
+                spans.count("runtime.readbacks", 1)
+                host = np.asarray(hist)
+                _count_attention(kernels, int(host[-1, 0]), int(host[-1, 1]))
             if self.collect_report:
-                reports = [
-                    _bookkeep_kernel(k, side[0], side[1], side[2], n_cc,
-                                     self.model)
-                    for k, side in zip(compiled.graph.topo_order(), sides)]
-            if spans.enabled():
-                _count_attention(compiled, needed, in_counts, sides)
-        return outs, InferenceReport(reports, self.strategy,
-                                     fused_wall_seconds=wall)
+                counts = host[:-1]
+                rows = functools.partial(self._report_rows, kernels, packed,
+                                         n_cc, self.model)
+        return outs, InferenceReport(rows, self.strategy,
+                                     fused_wall_seconds=wall, counts=counts)
+
+    @staticmethod
+    def _report_rows(kernels, packed: _PackedReport, n_cc: int, model
+                     ) -> List[KernelReport]:
+        """The per-kernel rows of one ``run``, from its packed report."""
+        spans.count("runtime.report_rows", 1)
+        return [_bookkeep_kernel(k, packed.field(i, 0), packed.field(i, 1),
+                                 packed.field(i, 2), n_cc, model)
+                for i, k in enumerate(kernels)]
 
     # -- batched (multi-tenant) execution -----------------------------------
     def launch_batch(self, compiled: CompiledModel,

@@ -18,10 +18,11 @@ The PR contract for whole-model fusion (DESIGN.md section 9):
   overlapped (exposed) K2P time of Section V-B2.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import hw
+from repro import hw, spans
 from repro.core import profiler, runtime
 from repro.models import gnn as gnn_models
 
@@ -82,16 +83,101 @@ def test_fused_matches_float32_reference(model):
 
 
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_fused_report_matches_per_kernel(strategy):
-    _, (_, _, rep_p), (_, _, rep_f) = _run_both("gcn", strategy)
-    for kp, kf in zip(rep_p.kernels, rep_f.kernels):
+@pytest.mark.parametrize("model", ["gcn", "gat"])
+def test_fused_report_matches_per_kernel(model, strategy):
+    """The fused report -- histogram counted on the device, rows built
+    on first read from the packed buffers -- equals the per-kernel
+    engine's, field by field."""
+    _, (_, _, rep_p), (_, _, rep_f) = _run_both(model, strategy)
+    np.testing.assert_array_equal(rep_p.histogram, rep_f.histogram)
+    assert rep_f.histogram.dtype == rep_p.histogram.dtype
+    assert len(rep_p.kernels) == len(rep_f.kernels) == len(rep_f.counts)
+    for kp, kf, counts in zip(rep_p.kernels, rep_f.kernels, rep_f.counts):
         np.testing.assert_array_equal(kp.histogram, kf.histogram)
+        np.testing.assert_array_equal(kp.histogram, counts)
+        assert kp.name == kf.name and kp.num_tasks == kf.num_tasks
         assert kp.makespan_cycles == kf.makespan_cycles
+        assert kp.utilization == kf.utilization
         assert kp.k2p_seconds == kf.k2p_seconds
         np.testing.assert_array_equal(kp.dens_x, kf.dens_x)
         np.testing.assert_array_equal(kp.dens_y, kf.dens_y)
+    assert rep_p.total_cycles == rep_f.total_cycles
+    assert rep_p.k2p_seconds == rep_f.k2p_seconds
     assert rep_f.fused_wall_seconds is not None
     assert rep_f.wall_seconds == rep_f.fused_wall_seconds > 0.0
+
+
+def _profile(tmp_path):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(tmp_path), profiler_options=opts)
+
+
+def test_report_is_one_readback_and_rows_wait_for_a_reader(tmp_path):
+    """Each ``run`` copies one array to the host (``runtime.readbacks``);
+    the per-kernel rows are built only when ``kernels`` is read
+    (``runtime.report_rows``), which copies the packed buffers once."""
+    b = gnn_models.build_dense("gat", "CO", scale=0.12, seed=2)
+    fused = runtime.FusedModelExecutor()
+    fused.run(b.compiled, b.tensors)
+    with _profile(tmp_path):
+        _, rep = fused.run(b.compiled, b.tensors)
+        assert spans.snapshot()[1]["runtime.readbacks"] == 1
+        fused.run(b.compiled, b.tensors)
+        rep.histogram
+        counters = spans.snapshot()[1]
+        assert counters["runtime.readbacks"] == 2
+        assert counters.get("runtime.report_rows", 0) == 0
+        rep.kernels
+        rep.total_cycles
+        counters = spans.snapshot()[1]
+        assert counters["runtime.report_rows"] == 1
+        assert counters["runtime.readbacks"] == 3
+
+
+def _side_leaves(model):
+    b = gnn_models.build_dense(model, "CO", scale=0.12, seed=2)
+    ex = runtime.FusedModelExecutor()
+    fn, needed = ex._program(b.compiled, b.tensors)
+    _, sides = jax.eval_shape(fn, b.tensors,
+                              ex._input_counts(needed, b.tensors))
+    return len(b.compiled.graph.kernels), len(jax.tree.leaves(sides))
+
+
+def test_side_output_leaves_do_not_grow_with_the_kernel_count():
+    (n_gcn, gcn), (n_gat, gat) = _side_leaves("gcn"), _side_leaves("gat")
+    assert n_gat > n_gcn
+    assert gcn == gat <= 4
+
+
+@pytest.mark.parametrize("model", ["gcn", "gat"])
+def test_packed_fields_are_the_unpacked_side_outputs(model):
+    """``planned_codes``, ``planned_formats`` and ``profiled_densities``,
+    read from the packed buffers, are bitwise (values, dtype and shape)
+    the side outputs of the same kernel walk returned unpacked."""
+    b = gnn_models.build_dense(model, "CO", scale=0.12, seed=2)
+    ex = runtime.FusedModelExecutor(keep_codes=True)
+    ex.run(b.compiled, b.tensors)
+    kernels = b.compiled.graph.topo_order()
+    flows = ex._resolved_flows(b.compiled)
+    needed = ex._needed_inputs(flows)
+
+    def unpacked(tensors, in_counts):
+        env = dict(tensors)
+        profiles = {(name, blk): profiler.BlockProfile(
+            counts, tuple(env[name].shape), blk)
+            for (name, blk), counts in zip(needed, in_counts)}
+        return ex._trace_kernels(kernels, flows, env, profiles)
+
+    sides = jax.jit(unpacked)(b.tensors, ex._input_counts(needed, b.tensors))
+    assert set(ex.planned_codes) == {k.out for k in kernels}
+    for k, side in zip(kernels, sides):
+        for got, want in ((ex.planned_codes[k.out], side[0]),
+                          (ex.profiled_densities[k.out], side[3]),
+                          (ex.planned_formats[k.out], side[4])):
+            want = np.asarray(want)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(got, want, err_msg=k.out)
 
 
 def test_one_jitted_call_per_inference():
