@@ -1,26 +1,18 @@
-"""Engine ladder: seed host-loop vs per-kernel executor vs fused model.
+"""Executor checks on the CPU: format-aware planning and GAT attention.
 
-Three generations of the same inference:
+* ``--formats`` -- density sweep of format-aware planning (row-CSR vs the
+  block path) through ``FusedModelExecutor``, with dense-oracle parity;
+  writes ``format_rows`` and the measured crossover into
+  ``BENCH_engine.json``.
+* ``--gat`` -- the GAT model through ``FusedModelExecutor`` against an
+  independent float64 NumPy oracle, plus the per-head plan evidence.
 
-* ``SeedHostLoopEngine`` -- the seed path, a Python triple loop over
-  (I, J, K) blocks with a host-side ``Primitive(int(code))`` dispatch per
-  reduction step (one eager XLA launch per block pair).  Kept here (not in
-  ``core``) purely as the benchmark baseline.
-* ``DynasparseEngine`` -- one cached jit-compiled executor call PER KERNEL
-  (profile -> plan -> masked block contraction -> fused epilogue in a
-  single XLA program each).
-* ``FusedModelExecutor`` -- the WHOLE model as one jit-compiled program:
-  layer l+1's K2P plan chains from layer l's writeback density profile
-  (no per-kernel re-profiling, no host round-trips between layers).
-
-Wall clocks are steady-state (first run warms compile/dispatch caches) and
-include each engine's host report bookkeeping, so the columns are
-apples-to-apples end-to-end latencies.  ``BENCH_engine.json`` carries the
-perf trajectory for the ROADMAP scaling work; the fused column is the
-serving-path number.
+Timings here are CPU wall clocks for the format sweep only; speed claims
+come from the chip benchmark (``bench/``).
 
   PYTHONPATH=src python -m benchmarks.run --only engine
-  PYTHONPATH=src python -m benchmarks.bench_engine --smoke   # CI exercise
+  PYTHONPATH=src python -m benchmarks.bench_engine --formats --smoke
+  PYTHONPATH=src python -m benchmarks.bench_engine --gat --smoke
 """
 from __future__ import annotations
 
@@ -34,12 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, geomean
-from repro.core import analyzer, compiler, runtime, scheduler
+from benchmarks.common import emit
+from repro.core import compiler, runtime
 from repro.core.ir import Activation, AggOp, KernelType
-from repro.core.perf_model import FPGACostModel, Format, Primitive, \
-    TPUCostModel
-from repro.core.profiler import block_density
+from repro.core.perf_model import Format, Primitive, TPUCostModel
 from repro.data import graphs as graph_data
 from repro.jax_cache import configure_compile_cache
 from repro.models import gnn as gnn_models
@@ -49,77 +39,10 @@ _OUT = pathlib.Path(__file__).resolve().parents[1] / "BENCH_engine.json"
 
 def _merge_json(update: dict) -> None:
     """Merge ``update`` into BENCH_engine.json, preserving other sections
-    (the engine-ladder rows and the format sweep write independently)."""
+    (the format sweep and the GAT row write independently)."""
     data = json.loads(_OUT.read_text()) if _OUT.exists() else {}
     data.update(update)
     _OUT.write_text(json.dumps(data, indent=2) + "\n")
-
-
-class SeedHostLoopEngine:
-    """The seed engine's execution path: per-block host dispatch (eager)."""
-
-    def __init__(self, strategy: str = "dynamic"):
-        self.strategy = strategy
-        self.model = FPGACostModel()
-
-    def run(self, compiled, tensors):
-        env = dict(tensors)
-        for k in compiled.graph.topo_order():
-            env[k.out] = self._run_kernel(k, env)
-        return env[compiled.graph.kernels[-1].out]
-
-    def _run_kernel(self, k, env):
-        bm, bk, bn = k.block_dims
-        if k.kernel_type == KernelType.AGGREGATE:
-            x = env["A" if k.agg_op == AggOp.SUM else "A_mean"]
-        else:
-            x = env[k.lhs]
-        y = env[k.rhs]
-        dx = np.asarray(block_density(x, (bm, bk)))
-        dy = np.asarray(block_density(y, (bk, bn)))
-        codes, _ = analyzer.plan_kernel_host(
-            self.strategy, dx, dy, k.block_dims, self.model,
-            kernel_type=k.kernel_type)
-        out = self._blocked_matmul(x, y, codes, (bm, bk, bn))
-        if k.epilogue_add is not None:
-            out = out + env[k.epilogue_add] * k.epilogue_scale
-        if k.activation_enabled:
-            if k.activation == Activation.RELU:
-                out = jax.nn.relu(out)
-            elif k.activation == Activation.PRELU:
-                out = jnp.where(out >= 0, out, 0.25 * out)
-        return out
-
-    def _blocked_matmul(self, x, y, codes, block):
-        bm, bk, bn = block
-        m, n = x.shape[0], y.shape[1]
-        I, J, K = codes.shape
-        pm, pk_ = (-m) % bm, (-x.shape[1]) % bk
-        pn = (-n) % bn
-        xp = jnp.pad(x, ((0, pm), (0, pk_)))
-        yp = jnp.pad(y, ((0, pk_), (0, pn)))
-        rows = []
-        for i in range(I):
-            cols = []
-            for j in range(J):
-                acc = jnp.zeros((bm, bn), jnp.float32)
-                for t in range(K):
-                    if Primitive(int(codes[i, j, t])) == Primitive.SKIP:
-                        continue
-                    xblk = jax.lax.dynamic_slice(
-                        xp, (i * bm, t * bk), (bm, bk))
-                    yblk = jax.lax.dynamic_slice(
-                        yp, (t * bk, j * bn), (bk, bn))
-                    acc = acc + jnp.dot(xblk, yblk,
-                                        preferred_element_type=jnp.float32)
-                cols.append(acc)
-            rows.append(jnp.concatenate(cols, axis=1))
-        out = jnp.concatenate(rows, axis=0)
-        return out[:m, :n].astype(jnp.promote_types(x.dtype, y.dtype))
-
-
-def _time(fn, repeats: int) -> float:
-    return _time_paired([fn], repeats)[0]
 
 
 def _time_paired(fns, repeats: int) -> list:
@@ -164,7 +87,7 @@ def _er_bundle(model: str, n: int, density: float, *, f_in: int = 64,
 
 
 def _dense_oracle(compiled, tensors):
-    """Plain jnp.dot walk with the engines' epilogue semantics."""
+    """Plain jnp.dot walk with the executor's epilogue semantics."""
     env = dict(tensors)
     for k in compiled.graph.topo_order():
         if k.kernel_type == KernelType.AGGREGATE:
@@ -192,8 +115,8 @@ def run_formats(*, smoke: bool = False, write_json: bool = True) -> list:
     Aggregate kernels carry enough arithmetic for the row-CSR-vs-block
     decision to bite in both directions across the sweep: row-CSR wins at
     the sparse end and the planner falls back to the block path (fill
-    guard and transform cost) at the dense end.  Both engines run the SAME
-    fused program shape; only the format decision differs.  ``csr_rmax``
+    guard and transform cost) at the dense end.  Both executors run the
+    SAME program shape; only the format decision differs.  ``csr_rmax``
     is deliberately small: the padded row format's conversion AND gather
     costs scale with rmax, so a tight row budget is what makes the sparse
     end pay -- the fill guard then vetoes CSR exactly where the budget no
@@ -292,26 +215,15 @@ def _gat_oracle(compiled, tensors):
     return env[compiled.graph.kernels[-1].out]
 
 
-def run_gat(*, smoke: bool = False, write_json: bool = True,
-            repeats: int = 3) -> list:
-    """GAT row (DESIGN.md §17): dynamic attention sparsity through both
-    engines -- per-kernel vs fused wall clocks, BITWISE fused parity, an
-    independent float64 oracle check, and the per-head plan evidence (each
-    head's aggregate planned from that head's thresholded attention
-    profile)."""
+def run_gat(*, write_json: bool = True) -> list:
+    """GAT row (DESIGN.md §17): dynamic attention sparsity through the
+    executor -- agreement with an independent float64 oracle, and the
+    per-head plan evidence (each head's aggregate planned from that head's
+    thresholded attention profile)."""
     b = gnn_models.build_dense("gat", "CO", scale=0.12, seed=2)
     last = b.compiled.graph.kernels[-1].out
-    per_eng = runtime.DynasparseEngine()
-    fused_eng = runtime.FusedModelExecutor()
-    per_s, fused_s = _time_paired(
-        [lambda: per_eng.run(b.compiled, b.tensors)[0][last],
-         lambda: fused_eng.run(b.compiled, b.tensors)[0][last]], repeats)
     probe = runtime.FusedModelExecutor(keep_codes=True)
     env_f, _ = probe.run(b.compiled, b.tensors)
-    env_p, _ = runtime.DynasparseEngine(keep_codes=True).run(
-        b.compiled, b.tensors)
-    bitwise = bool(np.array_equal(np.asarray(env_p[last]),
-                                  np.asarray(env_f[last])))
     oracle = _gat_oracle(b.compiled, b.tensors)
     oracle_ok = bool(np.allclose(np.asarray(env_f[last]), oracle,
                                  atol=3e-4, rtol=3e-4))
@@ -326,109 +238,40 @@ def run_gat(*, smoke: bool = False, write_json: bool = True,
                 and not np.array_equal(heads[l1[0]], heads[l1[1]]))
     row = {
         "model": "gat", "dataset": "CO", "scale": 0.12,
-        "per_kernel_s": per_s, "fused_s": fused_s,
-        "fused_vs_per_kernel_speedup": (per_s / fused_s if fused_s > 0
-                                        else float("inf")),
-        "bitwise_parity": bitwise, "oracle_ok": oracle_ok,
+        "oracle_ok": oracle_ok,
         "per_head_plan_histograms": hist,
         "layer1_head_plans_distinct": bool(distinct),
     }
-    emit("engine.gat.CO", fused_s * 1e6,
-         f"per-kernel={per_s*1e6:.0f}us bitwise={bitwise} "
+    emit("engine.gat.CO", 0.0,
          f"oracle={oracle_ok} heads_distinct={distinct}")
     if write_json:
         _merge_json({"gat_rows": [row]})
     return [row]
 
 
-def run(fast: bool = True, *, smoke: bool = False,
-        write_json: bool = True) -> list:
-    if smoke:
-        models, datasets, repeats = ("gcn",), ("CO",), 3
-    elif fast:
-        models, datasets, repeats = ("gcn", "sage"), ("CO",), 3
-    else:
-        models, datasets, repeats = ("gcn", "sage", "gin", "sgc"), \
-            ("CO", "CI"), 3
-    scale = 0.12
-    rows = []
-    for model in models:
-        for ds in datasets:
-            b = gnn_models.build_dense(model, ds, scale=scale, seed=0)
-            for strategy in ("dynamic", "s1", "s2", "gemm"):
-                eng = runtime.DynasparseEngine(strategy=strategy)
-                fused_eng = runtime.FusedModelExecutor(strategy=strategy)
-                unified_s, fused_s = _time_paired(
-                    [lambda: b.run(eng)[0],
-                     lambda: fused_eng.run(b.compiled, b.tensors)[0]],
-                    repeats + 2)
-                seed_eng = SeedHostLoopEngine(strategy)
-                seed_s = _time(
-                    lambda: seed_eng.run(b.compiled, b.tensors), repeats)
-                speedup = seed_s / unified_s if unified_s > 0 else float("inf")
-                fused_speedup = (unified_s / fused_s if fused_s > 0
-                                 else float("inf"))
-                rows.append({
-                    "model": model, "dataset": ds, "strategy": strategy,
-                    "scale": scale,
-                    "seed_host_loop_s": seed_s,
-                    "unified_executor_s": unified_s,
-                    "fused_executor_s": fused_s,
-                    "speedup": speedup,
-                    "fused_vs_per_kernel_speedup": fused_speedup,
-                })
-                emit(f"engine.{model}.{ds}.{strategy}", unified_s * 1e6,
-                     f"seed={seed_s*1e6:.0f}us speedup={speedup:.1f}x "
-                     f"fused={fused_s*1e6:.0f}us (+{fused_speedup:.2f}x)")
-    gm = geomean(r["speedup"] for r in rows)
-    gm_fused = geomean(r["fused_vs_per_kernel_speedup"] for r in rows)
-    if write_json:
-        _merge_json({
-            "bench": "seed host-loop vs per-kernel executor vs fused model",
-            "device": jax.default_backend(),
-            "repeats": repeats,
-            "rows": rows,
-            "geomean_speedup": gm,
-            "geomean_fused_vs_per_kernel": gm_fused,
-        })
-    emit("engine.geomean_speedup", 0.0, f"{gm:.2f}x -> {_OUT.name}")
-    emit("engine.geomean_fused_vs_per_kernel", 0.0, f"{gm_fused:.2f}x")
-    return rows
-
-
 if __name__ == "__main__":
     configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: one model/dataset, no BENCH_engine.json "
-                         "rewrite; exercises all three engines and fails if "
-                         "the fused path regresses vs per-kernel")
-    ap.add_argument("--full", action="store_true",
-                    help="all four models x both datasets")
+                    help="CI mode: smallest sizes, gates on, no "
+                         "BENCH_engine.json rewrite.  Without --formats "
+                         "or --gat, both run")
     ap.add_argument("--formats", action="store_true",
-                    help="run ONLY the format-aware density sweep "
-                         "(row-CSR vs block path); with --smoke it gates "
-                         "on parity AND row-CSR winning at the sparsest "
-                         "point")
+                    help="the format-aware density sweep (row-CSR vs "
+                         "block path); with --smoke it gates on parity AND "
+                         "row-CSR winning at the sparsest point")
     ap.add_argument("--gat", action="store_true",
-                    help="run ONLY the GAT attention row; with --smoke it "
-                         "gates on bitwise fused-vs-per-kernel parity and "
+                    help="the GAT attention row; with --smoke it gates on "
                          "the independent float64 oracle")
-    ap.add_argument("--tol", type=float, default=1.15,
-                    help="smoke gate: fail if fused > tol * per-kernel. "
-                         "The default suits a quiet machine; CI's shared "
-                         "runners pass a looser value that still catches "
-                         "the do-more-work class of regression")
     args = ap.parse_args()
-    if args.gat:
-        gat_rows = run_gat(smoke=args.smoke, write_json=not args.smoke)
+    both = not (args.gat or args.formats)
+    if args.gat or both:
+        gat_rows = run_gat(write_json=not args.smoke)
         if args.smoke:
-            bad = [r for r in gat_rows
-                   if not (r["bitwise_parity"] and r["oracle_ok"])]
+            bad = [r for r in gat_rows if not r["oracle_ok"]]
             if bad:
-                sys.exit(f"gat parity gate failed: {bad}")
-        sys.exit(0)
-    if args.formats:
+                sys.exit(f"gat oracle gate failed: {bad}")
+    if args.formats or both:
         fmt_rows = run_formats(smoke=args.smoke, write_json=not args.smoke)
         if args.smoke:
             bad = [r for r in fmt_rows if not r["parity_ok"]]
@@ -438,11 +281,3 @@ if __name__ == "__main__":
             if sparsest["csr_kernels"] == 0 or sparsest["speedup"] <= 1.0:
                 sys.exit("row-CSR does not win at the sparsest point: "
                          f"{sparsest}")
-        sys.exit(0)
-    bench_rows = run(fast=not args.full, smoke=args.smoke,
-                     write_json=not args.smoke)
-    if args.smoke:
-        slow = [r for r in bench_rows
-                if r["fused_executor_s"] > args.tol * r["unified_executor_s"]]
-        if slow:
-            sys.exit(f"fused executor slower than per-kernel: {slow}")

@@ -4,9 +4,9 @@ Measures the whole request -> bucket -> profile -> plan -> execute pipeline
 end to end over a mixed-size query stream (each request its own graph,
 hence its own density profile):
 
-* **naive** -- one per-kernel ``DynasparseEngine.run`` per request
-  (``GraphServeEngine.run_naive``): same pad-to-bucket admission, but one
-  dispatch chain + host bookkeeping per request, no batching;
+* **naive** -- one single-inference ``FusedModelExecutor.run`` per
+  request (``GraphServeEngine.run_naive``): same pad-to-bucket admission,
+  but one dispatch per request, no batching;
 * **served** -- ``GraphServeEngine.serve``: shape-bucketed admission waves
   through the batched fused program (one jitted dispatch per wave,
   profile-chained K2P planning, no per-request host bookkeeping);
@@ -22,7 +22,8 @@ Per engine: p50/p99 per-request latency (a served request's latency is its
 wave's wall clock -- requests share the dispatch), aggregate throughput
 (requests/s), and per-wave padding efficiency (real/slots occupancy from
 ``InferenceReport.wave_real``/``wave_slots``).  Timing is best-of-N with
-the two engines interleaved per round, same rationale as ``bench_engine``.
+the two paths interleaved per round, so slow drift in machine load cancels
+instead of showing up as a speedup.
 ``BENCH_serving.json`` carries
 the serving perf trajectory (sync rows + a continuous row per model);
 ``--smoke`` is the CI gate (bitwise served-vs-naive parity + a loose

@@ -20,7 +20,8 @@ from benchmarks import (bench_engine, bench_serving, fig13_runtime_overhead,
 from repro.jax_cache import configure_compile_cache
 
 SUITES = {
-    "engine": lambda full: bench_engine.run(fast=not full),
+    "engine": lambda full: (bench_engine.run_formats(),
+                            bench_engine.run_gat()),
     "serving": lambda full: bench_serving.run(fast=not full),
     "table4": lambda full: table4_perf_model.run(fast=not full),
     "table7": lambda full: table7_k2p.run(),

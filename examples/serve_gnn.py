@@ -79,7 +79,7 @@ def main():
           f"{args.n / wall:.1f} req/s, "
           f"wave walls p50={np.median(eng.wave_walls) * 1e3:.2f}ms")
 
-    naive = eng.run_naive(reqs)           # warm the per-kernel executables
+    naive = eng.run_naive(reqs)           # warm the single-inference programs
     t0 = time.perf_counter()
     naive = eng.run_naive(reqs)
     naive_wall = time.perf_counter() - t0

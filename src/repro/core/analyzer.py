@@ -178,7 +178,7 @@ def plan_format(
     the caller keeps the block path with ZERO added trace.  Otherwise a
     traced () int32 ``Format`` code from the same density grids the
     primitive plan used, so identical profiles give identical decisions in
-    the per-kernel and fused engines (the bitwise-parity invariant).
+    the single-inference and wave programs (the bitwise-parity invariant).
 
     The model sees Fig. 13's full accounting: the lhs nonzero count
     (reconstructed exactly from the ragged-aware block densities), the

@@ -1,8 +1,9 @@
 """The unified Dynasparse executor: profile -> plan -> execute in one ``jit``.
 
-This is the single execution path for the paper's mechanism -- both the
-GNN engine (``core.runtime.DynasparseEngine``) and the LM layers
-(``models.layers``) run every kernel through it.  The whole pipeline --
+This is the single execution path for the paper's mechanism -- the GNN
+executor (``core.runtime.FusedModelExecutor``) inlines it for every
+matmul kernel of its whole-model program, and the LM layers
+(``models.layers``) call it too.  The whole pipeline --
 
     profile block densities  ->  plan_codes (any strategy, traced)  ->
     one batched block contraction that applies the plan as a mask  ->
@@ -237,7 +238,8 @@ def dynasparse_matmul(
         # batched matmul.  Blocked (I, K, bm, bk) operands are relaid out
         # into the TPU's (8, 128) tiles and back, and their 5-D contraction
         # compiles to convolutions whose dimension order differs between
-        # the per-kernel and the wave programs, breaking bitwise equality.
+        # the single-inference and the wave programs, breaking bitwise
+        # equality.
         keep = (codes != Primitive.SKIP).transpose(1, 0, 2)  # (J, I, K)
         mask = jnp.repeat(jnp.repeat(keep, bm, axis=1), bk, axis=2)
         xm = jnp.where(mask, formats.pad_to_tiles(x, (bm, bk))[None], 0)
@@ -344,11 +346,8 @@ def attention_adjacency(
 ) -> DynasparseResult:
     """Masked edge softmax over the adjacency support (GAT), thresholded.
 
-    The one attention implementation BOTH engines execute (DESIGN.md §17)
-    -- ``DynasparseEngine`` dispatches it standalone, the fused walk
-    inlines it -- which is what keeps fused-vs-per-kernel outputs bitwise
-    identical for GAT just like ``dynasparse_matmul`` does for the matmul
-    kernels.
+    The one attention implementation (DESIGN.md §17), inlined by the
+    executor's single-inference and wave programs alike.
 
     * ``a`` is the (n, n) normalized adjacency; only its nonzero SUPPORT
       matters (scores are computed fresh, the mask restricts softmax to
@@ -379,13 +378,14 @@ def attention_adjacency(
     out_dtype = jnp.promote_types(a.dtype, z.dtype)
     support = a != 0
     # barrier: scores must be computed against the MATERIALIZED z.  Without
-    # it, the fused whole-model program (where z's producing Update matmul
-    # is in the same trace) may reassociate/refuse the projection against
-    # z's producer -- fewer FLOPs, different rounding -- and the engines
-    # stop being bitwise equal.  The two (f,) projections are one stacked
-    # (n, f) x (f, 2) dot for the same reason: a single-column dot gets
-    # rewritten to a context-dependent reduction, the 2-column one compiles
-    # to the same stable contraction in both programs.
+    # it, the whole-model program (where z's producing Update matmul is in
+    # the same trace) may reassociate/refuse the projection against z's
+    # producer -- fewer FLOPs, different rounding -- and the single-
+    # inference and wave programs stop being bitwise equal.  The two (f,)
+    # projections are one stacked (n, f) x (f, 2) dot for the same reason:
+    # a single-column dot gets rewritten to a context-dependent reduction,
+    # the 2-column one compiles to the same stable contraction in both
+    # programs.
     zf = jax.lax.optimization_barrier(z.astype(jnp.float32))
     att = jnp.concatenate([att_src, att_dst], axis=1).astype(jnp.float32)
     s = jnp.dot(zf, att, preferred_element_type=jnp.float32)  # (n, 2)
@@ -431,9 +431,8 @@ def head_combine(
     layer plans from it.  ELU keeps zeros and signs, so the combined
     density is that of the sum, never thinned as ReLU thins it.
 
-    The one implementation both engines execute, which keeps their outputs
-    bitwise equal.  Nothing is planned: ``codes`` is one SKIP step, as the
-    combination performs no block product.
+    Nothing is planned: ``codes`` is one SKIP step, as the combination
+    performs no block product.
     """
     if mean:
         out = functools.reduce(jnp.add, heads[1:], heads[0]) / len(heads)

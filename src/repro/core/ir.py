@@ -26,7 +26,7 @@ class KernelType(enum.IntEnum):
     # produces a (|V|, |V|) attention matrix whose sparsity is input- and
     # head-dependent -- the operand whose density the K2P planner cannot
     # know until runtime.  Not a matmul: executed by a dedicated traced
-    # function (``dynasparse.attention_adjacency``) in both engines.
+    # function (``dynasparse.attention_adjacency``).
     ATTENTION = 3
 
 

@@ -259,10 +259,11 @@ class TPUCostModel:
         blocks are both nonzero -- the steps the block path cannot SKIP; each
         is charged one block-GEMM (an upper bound that SpDMM/SPMM tighten,
         but launch overhead dominates at these block sizes).  The transform
-        cost is charged in full to EVERY kernel even when the fused walk will
-        reuse one conversion -- both engines must reach identical decisions
-        from identical densities (the bitwise-parity invariant), and the
-        per-kernel engine really does convert per kernel.
+        cost is charged in full to EVERY kernel even when the executor will
+        reuse one conversion, so a decision is a pure function of the
+        kernel's own densities: a kernel run alone through
+        ``dynasparse_matmul`` reaches the same decision and really does
+        convert for itself.
         """
         bm, bk, bn_ = block_dims
         block_s = occupied_steps * self.gemm_seconds(bm, bk, bn_)

@@ -2,17 +2,14 @@
 
 Two entry points:
 
-* :class:`DynasparseEngine` -- executes a compiled GNN (IR from
-  ``core.compiler``) with REAL numerics.  Every kernel runs as ONE traced,
-  jit-compiled call through the unified executor
-  (``core.dynasparse.dynasparse_matmul``): the executor profiles block
-  densities, runs the Analyzer (``analyzer.plan_codes`` -- Algorithm 7 or a
-  static strategy) and dispatches every reduction step to its primitive
-  inside the same XLA program.  The Python host plays the MicroBlaze's role
-  for bookkeeping only (Alg. 8 makespan, histograms, reports); compiled
-  executables are cached per (shapes, block, strategy, epilogue) signature,
-  so repeated kernels/layers re-launch without re-tracing.  See DESIGN.md
-  section 1.
+* :class:`FusedModelExecutor` -- executes a compiled GNN (IR from
+  ``core.compiler``) with REAL numerics as one jit-compiled program per
+  inference (``run``) or per wave of stacked inferences (``run_batch``).
+  Every kernel is planned (``analyzer`` -- Algorithm 7 or a static
+  strategy) and executed inside that program through
+  ``core.dynasparse``; the host plays the MicroBlaze's role for
+  bookkeeping only (Alg. 8 makespan, histograms, reports).  See DESIGN.md
+  sections 1 and 9.
 
 * :func:`simulate_inference` -- pure cost-model execution (no numerics):
   given per-tensor density statistics it produces the predicted latency of a
@@ -70,7 +67,6 @@ class KernelReport:
     # scheduling + histogram).  The K2P decisions themselves execute inside
     # the jitted executable; their soft-processor cost is k2p_seconds.
     k2p_wall_seconds: float = 0.0
-    wall_seconds: float = 0.0        # host wall clock (real-exec mode only)
     dens_x: Optional[np.ndarray] = None   # (I, K) profiled lhs densities
     dens_y: Optional[np.ndarray] = None   # (K, J) profiled rhs densities
 
@@ -82,8 +78,8 @@ class InferenceReport:
     # until a caller asks for it)
     rows: Union[List[KernelReport], Callable[[], List[KernelReport]]]
     strategy: str
-    # set by the fused whole-model executor: the single program's wall time
-    # (per-kernel walls are unobservable inside one XLA program).
+    # the program's wall time, set by the executor (kernels have no wall
+    # time of their own inside one XLA program); None when simulated
     fused_wall_seconds: Optional[float] = None
     # per-wave plumbing (set on the batched serving path): the dispatched
     # wave's batch width, and -- filled in by the admission layer, which is
@@ -123,10 +119,10 @@ class InferenceReport:
         The paper's runtime plans kernel l+1 on the soft processor while the
         accelerator executes kernel l (Section V-B2), so only the first
         kernel's planning plus any per-kernel planning time EXCEEDING the
-        previous kernel's execution is exposed.  The fused executor realizes
+        previous kernel's execution is exposed.  The executor realizes
         exactly this dependence structure (plan l+1 from l's writeback
         profile), so this is its modeled K2P overhead; ``k2p_seconds`` is
-        the non-overlapped sum the per-kernel path models.
+        the non-overlapped sum.
         """
         ks = self.kernels
         if not ks:
@@ -143,9 +139,7 @@ class InferenceReport:
 
     @property
     def wall_seconds(self) -> float:
-        if self.fused_wall_seconds is not None:
-            return self.fused_wall_seconds
-        return float(sum(k.wall_seconds for k in self.kernels))
+        return self.fused_wall_seconds or 0.0
 
     @property
     def histogram(self) -> np.ndarray:
@@ -289,7 +283,7 @@ def propagate_stats(
             raise NotImplementedError(
                 "attention kernels have no density-space model (their "
                 "operand density is input-dependent by construction); GAT "
-                "runs only through the real-numerics engines")
+                "runs only through the real-numerics executor")
         dx, dy = _operand_block_densities(k, env)
         _, bk, _ = k.block_dims
         # out block (i, j): 1 - prod_k (1 - dx[i,k] dy[k,j])^bk
@@ -369,7 +363,7 @@ def simulate_inference(
     ``n_cc`` cores, and the Table IV cost under ``model``
     (``FPGACostModel`` for the paper's numbers, ``TPUCostModel`` for the
     TPU adaptation).  ``strategy`` follows the same contract as
-    :class:`DynasparseEngine`.  This is how the paper-table benchmarks
+    :class:`FusedModelExecutor`.  This is how the paper-table benchmarks
     evaluate graphs whose dense materialization would not fit this
     container (NELL/Reddit), mirroring how the paper's own latency derives
     from its model + measured densities + Alg. 8 load balance.
@@ -381,7 +375,7 @@ def simulate_inference(
         if k.kernel_type == KernelType.ATTENTION:
             raise NotImplementedError(
                 "attention kernels have no density-space cost model; GAT "
-                "runs only through the real-numerics engines")
+                "runs only through the real-numerics executor")
         dx, dy = _operand_block_densities(k, stats_env)
         codes, costs = analyzer.plan_kernel_host(
             strategy, dx, dy, k.block_dims, model,
@@ -396,7 +390,7 @@ def simulate_inference(
 
 
 # ---------------------------------------------------------------------------
-# Real-numerics engines.
+# Real-numerics execution.
 # ---------------------------------------------------------------------------
 
 _AGG_PRE = {AggOp.SUM: "A", AggOp.MEAN: "A_mean"}
@@ -417,23 +411,11 @@ def _agg_lhs_name(k: KernelIR) -> str:
     return name
 
 
-def _combine(k: KernelIR, env: Dict[str, jnp.ndarray]) -> DynasparseResult:
-    """A HEAD_COMBINE kernel on the tensors of ``env`` (both engines)."""
-    n2 = k.scheme.n2
-    return head_combine(
-        tuple(env[name] for name in k.heads_in),
-        env[k.epilogue_add] if k.epilogue_add is not None else None,
-        mean=k.head_mean,
-        activation=k.activation.value if k.activation_enabled else "none",
-        out_block=(n2, n2))
-
-
 def _bookkeep_kernel(k: KernelIR, codes, dens_x, dens_y, n_cc: int, model
                      ) -> KernelReport:
-    """Host bookkeeping from the planner's codes (the MicroBlaze's role):
-    Table IV per-task costs, Alg. 8 scheduling, primitive histogram, modeled
-    + measured K2P time.  Shared by the per-kernel and fused engines so both
-    report identically."""
+    """One kernel's report row from the planner's codes (the MicroBlaze's
+    role): Table IV per-task costs, Alg. 8 scheduling, primitive histogram,
+    modeled + measured K2P time."""
     codes = np.asarray(codes)
     dx = np.asarray(dens_x)
     dy = np.asarray(dens_y)
@@ -447,181 +429,6 @@ def _bookkeep_kernel(k: KernelIR, codes, dens_x, dens_y, n_cc: int, model
         makespan_cycles=sched.makespan, utilization=sched.utilization,
         k2p_seconds=_k2p_model_seconds(codes.size),
         k2p_wall_seconds=k2p_wall, dens_x=dx, dens_y=dy)
-
-
-class DynasparseEngine:
-    """Executes a compiled GNN through the unified jit-compiled executor.
-
-    Per kernel: one cached executable (profile -> plan -> dispatch -> fused
-    epilogue, all inside a single XLA program); the host derives the
-    ``KernelReport`` bookkeeping (primitive histogram, Alg. 8 makespan,
-    modeled + measured K2P time) from the planner's codes, which the
-    executor returns as side outputs.  The result's block-density profile
-    (fused at writeback) is kept in ``profiled_densities`` so layer l+1 can
-    be planned while layer l executes; :class:`FusedModelExecutor` is that
-    idea taken to its conclusion (the whole model as one program) -- keep
-    THIS engine for debugging/reports, it has real per-kernel wall clocks
-    and inspectable intermediates.
-
-    Contracts:
-
-    * ``strategy`` -- one of ``analyzer.STRATEGIES``: ``"dynamic"``
-      (Algorithm 7, per-partition-pair decisions from profiled densities),
-      ``"s1"`` (Aggregate->SpDMM / Update->GEMM), ``"s2"`` (all SpDMM),
-      ``"gemm"`` (all dense).  Fixed per engine so executables cache per
-      strategy; outputs are value-identical across strategies (dispatch
-      changes cost, never results).
-    * ``use_kernels`` -- route the non-SKIP branches through the Pallas
-      block-sparse kernels (``repro.kernels``) with ``tile``/``unroll``;
-      off-TPU they run in interpret mode, so leave False (XLA dot path)
-      unless exercising kernel code.  Numerics are preserved either way.
-    * density-profile shapes -- operand profiles follow the kernel's
-      ``block_dims``: an (I, K) grid for the lhs at (bm, bk) blocks and a
-      (K, J) grid for the rhs at (bk, bn) blocks.  Feature-matrix stats
-      live at (N2, N2) repo-wide; an Aggregate consumer reads features at
-      (N1, N2) fiber granularity by row-pooling (``_pool_rows`` /
-      ``profiler.BlockProfile.pool_rows``).  ``profiled_densities[out]``
-      is the post-epilogue writeback profile at (N2, N2).
-    * ``keep_codes=True`` additionally records every kernel's (I, J, K)
-      planner code grid in ``planned_codes`` (parity tests diff them).
-    """
-
-    def __init__(self, *, strategy: str = "dynamic",
-                 model: Optional[FPGACostModel] = None,
-                 n_cc: Optional[int] = None,
-                 use_kernels: bool = False,
-                 tile: Tuple[int, int] = (16, 16),
-                 unroll: int = 1,
-                 keep_codes: bool = False,
-                 format_aware: bool = True,
-                 csr_rmax: int = 64):
-        self.strategy = strategy
-        self.model = model or FPGACostModel()
-        self.n_cc = n_cc
-        self.use_kernels = use_kernels
-        self.tile = tile
-        self.unroll = unroll
-        # debug/report switch: record every kernel's planner code grid in
-        # ``planned_codes`` (the fused-vs-per-kernel parity tests diff them).
-        self.keep_codes = keep_codes
-        # format-aware K2P (DESIGN.md section 13).  True is safe with the
-        # default FPGACostModel: it has no format costs, so plan_format
-        # statically keeps the block path and the trace is unchanged.  The
-        # row-CSR path activates only under a model with
-        # ``select_format_traced`` (TPUCostModel).
-        self.format_aware = format_aware
-        self.csr_rmax = csr_rmax
-        # executable cache: signature -> partial-applied jitted executor.
-        # jax.jit has its own global trace cache; this local cache makes the
-        # hit/miss behavior observable (tests, benchmarks) and keeps key
-        # hashing in one place.
-        self._executors: Dict[tuple, functools.partial] = {}
-        self.cache_hits = 0
-        self.cache_misses = 0
-        self.profiled_densities: Dict[str, jnp.ndarray] = {}
-        self.planned_codes: Dict[str, np.ndarray] = {}
-        self.planned_formats: Dict[str, int] = {}
-
-    def run(self, compiled: CompiledModel, tensors: Dict[str, jnp.ndarray]
-            ) -> Tuple[Dict[str, jnp.ndarray], InferenceReport]:
-        env = dict(tensors)
-        n_cc = self.n_cc or compiled.partition.n_cc
-        self.profiled_densities = {}
-        self.planned_codes = {}
-        self.planned_formats = {}
-        reports: List[KernelReport] = []
-        for k in compiled.graph.topo_order():
-            t0 = time.perf_counter()
-            out, rep = self._run_kernel(k, env, n_cc)
-            env[k.out] = out
-            rep.wall_seconds = time.perf_counter() - t0
-            reports.append(rep)
-        return env, InferenceReport(reports, self.strategy)
-
-    # -- executor cache -----------------------------------------------------
-    def _executor(self, k: KernelIR, x: jnp.ndarray, y: jnp.ndarray,
-                  has_residual: bool) -> functools.partial:
-        activation = (k.activation.value if k.activation_enabled else "none")
-        scale = k.epilogue_scale if has_residual else 1.0
-        key = (k.kernel_type, k.block_dims, x.shape, str(x.dtype),
-               y.shape, str(y.dtype), self.strategy, has_residual,
-               scale, activation)
-        fn = self._executors.get(key)
-        if fn is not None:
-            self.cache_hits += 1
-            return fn
-        self.cache_misses += 1
-        n2 = k.scheme.n2
-        fn = functools.partial(
-            dynasparse_matmul,
-            strategy=self.strategy,
-            kernel_type=k.kernel_type,
-            epilogue_scale=scale,
-            activation=activation,
-            # feature stats live at (N2, N2) repo-wide; an Aggregate
-            # consumer mean-pools row blocks to N1 (see _pool_rows /
-            # _operand_block_densities), exact for element densities.
-            out_block=(n2, n2),
-            block=k.block_dims,
-            cost_model=self.model,
-            use_kernels=self.use_kernels,
-            tile=self.tile,
-            unroll=self.unroll,
-            format_aware=self.format_aware,
-            csr_rmax=self.csr_rmax)
-        self._executors[key] = fn
-        return fn
-
-    # -- one kernel ---------------------------------------------------------
-    def _run_kernel(self, k: KernelIR, env: Dict[str, jnp.ndarray],
-                    n_cc: int) -> Tuple[jnp.ndarray, KernelReport]:
-        if k.kernel_type == KernelType.HEAD_COMBINE:
-            # elementwise, shared with the fused walk like the attention
-            res = _combine(k, env)
-            self.profiled_densities[k.out] = res.out_density
-            if self.keep_codes:
-                self.planned_codes[k.out] = np.asarray(res.codes)
-                self.planned_formats[k.out] = int(res.fmt)
-            return res.out, _bookkeep_kernel(k, res.codes, res.dens_x,
-                                             res.dens_y, n_cc, self.model)
-        if k.kernel_type == KernelType.AGGREGATE:
-            x = env[_agg_lhs_name(k)]
-        else:
-            x = env[k.lhs]
-        y = env[k.rhs]
-
-        if k.kernel_type == KernelType.ATTENTION:
-            # masked edge-softmax, not a matmul: one shared traced function
-            # (the fused walk calls the identical one, so the produced
-            # attention matrix -- and every plan downstream of its profile
-            # -- is bitwise the same in both engines).
-            n2 = k.scheme.n2
-            res = attention_adjacency(
-                x, y, env[k.att_src], env[k.att_dst],
-                slope=k.att_slope, threshold=k.att_threshold,
-                out_block=(n2, n2))
-            self.profiled_densities[k.out] = res.out_density
-            if self.keep_codes:
-                self.planned_codes[k.out] = np.asarray(res.codes)
-                self.planned_formats[k.out] = int(res.fmt)
-            rep = _bookkeep_kernel(k, res.codes, res.dens_x, res.dens_y,
-                                   n_cc, self.model)
-            return res.out, rep
-
-        residual = env[k.epilogue_add] if k.epilogue_add is not None else None
-
-        # --- one traced call: profile -> plan -> dispatch -> epilogue ---
-        fn = self._executor(k, x, y, residual is not None)
-        res: DynasparseResult = fn(x, y, residual=residual)
-        self.profiled_densities[k.out] = res.out_density
-        if self.keep_codes:
-            self.planned_codes[k.out] = np.asarray(res.codes)
-            self.planned_formats[k.out] = int(res.fmt)
-
-        # --- host bookkeeping from the planner's codes (side outputs) ---
-        rep = _bookkeep_kernel(k, res.codes, res.dens_x, res.dens_y,
-                               n_cc, self.model)
-        return res.out, rep
 
 
 def _count_attention(kernels, support: int, kept: int) -> None:
@@ -644,10 +451,9 @@ def _count_attention(kernels, support: int, kept: int) -> None:
 class FusedModelExecutor:
     """Traces a full ``CompiledModel`` into one jit-compiled program.
 
-    Where :class:`DynasparseEngine` launches one cached executable per
-    kernel (and each kernel's trace re-profiles its own operands), this
-    executor walks the topologically-ordered kernel list inside a SINGLE
-    trace and chains the writeback profiles between layers:
+    The only code that executes a compiled model.  It walks the
+    topologically-ordered kernel list inside a SINGLE trace and chains the
+    writeback profiles between layers:
 
     * graph inputs (adjacency, features, weights) are profiled ONCE per
       (tensor identity, granularity) on the host and handed to the program
@@ -670,29 +476,32 @@ class FusedModelExecutor:
     between layers.  ``InferenceReport.k2p_exposed_seconds`` models the
     resulting overlapped soft-processor time.
 
+    ``strategy`` is one of ``analyzer.STRATEGIES``: ``"dynamic"``
+    (Algorithm 7), ``"s1"`` (Aggregate->SpDMM / Update->GEMM), ``"s2"``
+    (all SpDMM) or ``"gemm"`` (all dense); outputs are value-identical
+    across strategies (dispatch changes cost, never results).
+    ``use_kernels`` routes the non-SKIP steps through the Pallas
+    block-sparse kernels with ``tile``/``unroll`` (interpret mode off-TPU).
+
     Intermediate feature matrices live only inside the XLA program (they
     are temporaries, reused by buffer assignment, and are not returned
     unless ``keep_intermediates=True``); set ``donate=True`` to also donate
     the input tensor buffers when the caller will not reuse them.
 
-    The per-kernel :class:`DynasparseEngine` remains the debug/report path
-    (per-kernel wall clocks, ``profiled_densities`` inspection between
-    launches); this executor is the serving path.  Both report the same
-    ``InferenceReport`` bookkeeping -- histograms, Alg. 8 makespan,
-    modeled K2P time -- derived from the planner's codes.  The
-    single-inference program counts each kernel's primitives itself and
-    packs every kernel's codes and densities into one buffer per dtype, so
-    ``run`` copies only the small histogram to the host and builds the
-    per-kernel rows on their first read.  ``collect_report=False`` drops
-    the report altogether (the wave path still returns per-slot side
-    outputs, which it then leaves on the device).
+    The report (``InferenceReport``: histograms, Alg. 8 makespan, modeled
+    K2P time) derives from the planner's codes.  The single-inference
+    program counts each kernel's primitives itself and packs every
+    kernel's codes and densities into one buffer per dtype, so ``run``
+    copies only the small histogram to the host and builds the per-kernel
+    rows on their first read.  ``collect_report=False`` drops the report
+    altogether (the wave path still returns per-slot side outputs, which
+    it then leaves on the device).
 
-    ``run`` mirrors ``DynasparseEngine.run``'s contract (an env dict
-    containing the final output plus an ``InferenceReport``), so model
-    bundles (``models.gnn.DenseGNN``) accept either engine.  ``run_batch``
-    is the multi-tenant surface on top: one jitted call serving a stacked
-    WAVE of inferences over shared weights (``serving.graph_engine`` is
-    the request loop that feeds it).
+    ``run`` returns an env dict holding the final output plus the report
+    (``models.gnn.DenseGNN.run`` wraps it).  ``run_batch`` is the
+    multi-tenant surface on top: one jitted call serving a stacked WAVE of
+    inferences over shared weights (``serving.graph_engine`` is the
+    request loop that feeds it).
     """
 
     def __init__(self, *, strategy: str = "dynamic",
@@ -716,10 +525,10 @@ class FusedModelExecutor:
         self.keep_intermediates = keep_intermediates
         self.donate = donate
         self.keep_codes = keep_codes
-        # format-aware K2P, same contract as DynasparseEngine's: inert under
-        # the default FPGACostModel, active under TPUCostModel.  The fused
-        # walk additionally SHARES one on-the-fly conversion between kernels
-        # reading the same source tensor (see _trace_kernels).
+        # format-aware K2P (DESIGN.md section 13): inert under the default
+        # FPGACostModel, active under TPUCostModel.  The walk SHARES one
+        # on-the-fly conversion between kernels reading the same source
+        # tensor (see _trace_kernels).
         self.format_aware = format_aware
         self.csr_rmax = csr_rmax
         # serving knob: False skips the report -- no histogram readback
@@ -805,10 +614,10 @@ class FusedModelExecutor:
         at most ONCE -- the first kernel that wants CSR pays the D2S, later
         kernels reuse the view (a second cond converts only if no earlier
         kernel did).  The conversion is deterministic, so the reused view is
-        bitwise what the per-kernel engine rebuilds for itself, and each
+        bitwise what a kernel converting for itself would build, and each
         kernel's DECISION still charges the full transform cost (see
         ``TPUCostModel.select_format_traced``) so decisions stay a pure
-        function of the densities in both engines."""
+        function of the densities."""
         counts_env: Dict[str, profiler.BlockProfile] = {}
         # (source name, shape) -> (want so far, shared ELL view)
         ell_env: Dict[tuple, tuple] = {}
@@ -819,8 +628,15 @@ class FusedModelExecutor:
             # d2s and the writeback_profile
             with jax.named_scope(k.kernel_type.name.lower()):
                 if k.kernel_type == KernelType.HEAD_COMBINE:
-                    res = _combine(k, env)
                     n2c = k.scheme.n2
+                    res = head_combine(
+                        tuple(env[name] for name in k.heads_in),
+                        (env[k.epilogue_add]
+                         if k.epilogue_add is not None else None),
+                        mean=k.head_mean,
+                        activation=(k.activation.value
+                                    if k.activation_enabled else "none"),
+                        out_block=(n2c, n2c))
                     env[k.out] = res.out
                     counts_env[k.out] = profiler.BlockProfile(
                         res.out_counts, res.out.shape, (n2c, n2c))
@@ -1036,8 +852,8 @@ class FusedModelExecutor:
 
         Returns ``(env, report)`` where ``env`` holds the final output (all
         intermediates too iff ``keep_intermediates=True``) and ``report``
-        carries the same per-kernel bookkeeping as the per-kernel engine,
-        plus ``fused_wall_seconds`` (the single program's wall clock).
+        carries the per-kernel bookkeeping plus ``fused_wall_seconds`` (the
+        program's wall clock).
 
         The program returns its report packed (``_pack_report``); the
         report copies only the primitive histogram to the host.  The

@@ -1,12 +1,12 @@
 """GNN models (GCN / GraphSAGE / GIN / SGC / GAT) through the Dynasparse stack.
 
 The model IS its IR: ``core.compiler`` turns a ``GNNModelSpec`` + graph meta
-into Aggregate/Update kernels, and either the real-numerics engine
-(``core.runtime.DynasparseEngine``) or the cost-model simulator executes it.
-This module provides the bundle plumbing: weight init/pruning, dataset
-wiring, the two evaluation paths used by tests/benchmarks/examples, and
-:func:`reference_forward`, the plain float32 ``jax.numpy`` forward every
-engine is checked against.
+into Aggregate/Update kernels, and either the real-numerics executor
+(``core.runtime.FusedModelExecutor``) or the cost-model simulator executes
+it.  This module provides the bundle plumbing: weight init/pruning,
+dataset wiring, the two evaluation paths used by tests/benchmarks/examples,
+and :func:`reference_forward`, the plain float32 ``jax.numpy`` forward the
+executor is checked against.
 """
 from __future__ import annotations
 
@@ -184,17 +184,14 @@ class DenseGNN:
 
     def run(self, engine=None, *, strategy: Optional[str] = None
             ) -> Tuple[jnp.ndarray, runtime.InferenceReport]:
-        """One inference through the unified jit-compiled executor.
-
-        ``engine`` is either a :class:`runtime.DynasparseEngine` (one cached
-        executable per kernel -- the debug/report path) or a
+        """One inference through ``engine``, a
         :class:`runtime.FusedModelExecutor` (the whole model as ONE
-        jit-compiled program with layer-overlap K2P planning -- the serving
-        path); both share the ``run(compiled, tensors)`` contract.  Pass
-        ``strategy`` as a shortcut for ``DynasparseEngine(strategy=...)``.
+        jit-compiled program).  Pass ``strategy`` as a shortcut for
+        ``FusedModelExecutor(strategy=...)``.
         """
         if engine is None:
-            engine = runtime.DynasparseEngine(strategy=strategy or "dynamic")
+            engine = runtime.FusedModelExecutor(
+                strategy=strategy or "dynamic")
         elif strategy is not None and strategy != engine.strategy:
             raise ValueError(
                 f"strategy {strategy!r} conflicts with engine "

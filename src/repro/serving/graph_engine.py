@@ -31,9 +31,10 @@ of the fused whole-model executor:
 
 * **Bitwise request isolation.**  A request's computation depends only on
   its own slice of the wave and the shared weights, so outputs are
-  bitwise-identical to a per-request `core.runtime.DynasparseEngine` run
-  on the same padded tensors (:meth:`GraphServeEngine.run_naive` is that
-  oracle), regardless of admission order or wave composition --
+  bitwise-identical to the request served alone -- one single-inference
+  ``FusedModelExecutor.run`` on the same padded tensors
+  (:meth:`GraphServeEngine.run_naive` is that oracle) -- regardless of
+  admission order or wave composition --
   ``tests/test_graph_serving.py`` pins both properties for the whole
   model zoo.
 
@@ -157,9 +158,9 @@ class GraphServeEngine:
 
     * results come back in request order, each sliced to its request's
       true vertex count;
-    * outputs are bitwise-identical to :meth:`run_naive` (per-request
-      ``DynasparseEngine`` on the same padded tensors) and invariant to
-      admission order;
+    * outputs are bitwise-identical to :meth:`run_naive` (one
+      single-inference program per request on the same padded tensors)
+      and invariant to admission order;
     * ``executor.trace_count`` grows by at most one per shape bucket --
       waves are padded to ``slots`` requests so the batched program
       signature is unique per bucket;
@@ -254,18 +255,21 @@ class GraphServeEngine:
         # FPGACostModel; pass perf_model.TPUCostModel() to turn on row-CSR
         # format decisions, DESIGN.md section 13).  format_aware/csr_rmax
         # thread through to BOTH the serving executor and run_naive's
-        # oracle engine, so format decisions stay part of the bitwise
+        # executor, so format decisions stay part of the bitwise
         # serve == run_naive contract.
-        self.format_aware = cfg.format_aware
-        self.csr_rmax = cfg.csr_rmax
         self.executor = runtime.FusedModelExecutor(
             strategy=cfg.strategy, model=cfg.cost_model, n_cc=cfg.n_cc,
             donate=cfg.donate, collect_report=cfg.collect_report,
             keep_codes=cfg.keep_codes, format_aware=cfg.format_aware,
             csr_rmax=cfg.csr_rmax)
+        # run_naive's single-inference executor.  Never donating: its
+        # inputs include ``self.weights``, which every wave reads.
+        self._naive = runtime.FusedModelExecutor(
+            strategy=cfg.strategy, model=self.executor.model, n_cc=cfg.n_cc,
+            donate=False, format_aware=cfg.format_aware,
+            csr_rmax=cfg.csr_rmax)
         self._compiled: Dict[int, CompiledModel] = {}
         self._input_names: Dict[int, List[str]] = {}
-        self._naive: Optional[runtime.DynasparseEngine] = None
         # serving counters (benchmark/test observability)
         self.waves = 0
         self.served = 0
@@ -612,16 +616,11 @@ class GraphServeEngine:
     def run_naive(self, requests: Sequence[GraphRequest]
                   ) -> List[GraphResult]:
         """Per-request baseline AND bitwise parity oracle: the same
-        pad-to-bucket admission, but one per-kernel
-        ``DynasparseEngine.run`` per request -- no wave batching, one
-        dispatch chain plus host bookkeeping per request.  The serving
-        benchmark compares throughput against this; the tests compare
-        bits."""
-        if self._naive is None:
-            self._naive = runtime.DynasparseEngine(
-                strategy=self.strategy, model=self.executor.model,
-                n_cc=self.n_cc, format_aware=self.format_aware,
-                csr_rmax=self.csr_rmax)
+        pad-to-bucket admission, but one single-inference
+        ``FusedModelExecutor.run`` per request -- no wave batching, a
+        different program from the wave's scan over stacked slots.  The
+        serving benchmark compares throughput against this; the tests
+        compare bits."""
         results = []
         for req in requests:
             self._validate(req)

@@ -507,7 +507,7 @@ class MiniBatchServeEngine:
     One wave-batched pass answers every uncached seed across the batch of
     queries (duplicate vertices collapse to one request); results are
     bitwise equal to :meth:`oracle_queries` (per-seed ``run_naive``, i.e.
-    a per-request ``DynasparseEngine`` run) whatever the cache state.
+    one single-inference program per request) whatever the cache state.
     """
 
     def __init__(self, engine: GraphServeEngine, graph: HostGraph,
@@ -574,10 +574,11 @@ class MiniBatchServeEngine:
     def oracle_queries(self, queries: Sequence[Sequence[int]]
                        ) -> List[np.ndarray]:
         """Slow full-fidelity oracle: every seed sampled identically, run
-        one at a time through the engine's ``run_naive`` (a per-request
-        ``DynasparseEngine.run`` on the same padded tensors) -- no waves,
-        no cache.  The parity suites and the benchmark's parity gate
-        compare the serving path against this bitwise."""
+        one at a time through the engine's ``run_naive`` (one
+        single-inference ``FusedModelExecutor.run`` on the same padded
+        tensors) -- no waves, no cache.  The parity suites and the
+        benchmark's parity gate compare the serving path against this
+        bitwise."""
         planner = self.planner
         out = []
         for seeds in queries:

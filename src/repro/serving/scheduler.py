@@ -77,7 +77,8 @@ The clock is injectable (``clock=``, default ``time.monotonic``) so the
 whole policy runs deterministically under a fake clock in tests
 (``tests/test_continuous_serving.py``); numerics never depend on it --
 continuous results are bitwise-identical to
-``GraphServeEngine.run_naive`` on the same requests whatever the arrival
+``GraphServeEngine.run_naive`` (each request served alone by the
+single-inference program) on the same requests whatever the arrival
 order, deadlines, or clock jitter.
 """
 from __future__ import annotations

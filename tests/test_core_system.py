@@ -99,9 +99,9 @@ def test_steal_rebalance_never_hurts(rng):
 @pytest.mark.parametrize("strategy", ["dynamic", "s1", "s2", "gemm"])
 def test_engine_matches_dense_reference(model, strategy):
     b = gnn_models.build_dense(model, "CO", scale=0.15, seed=1)
-    out, rep = b.run(runtime.DynasparseEngine(strategy=strategy))
+    out, rep = b.run(runtime.FusedModelExecutor(strategy=strategy))
     # dense oracle: run the same IR forcing GEMM everywhere
-    want, _ = b.run(runtime.DynasparseEngine(strategy="gemm"))
+    want, _ = b.run(runtime.FusedModelExecutor(strategy="gemm"))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-3, rtol=2e-3)
     assert rep.total_cycles > 0

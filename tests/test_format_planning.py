@@ -11,8 +11,10 @@ model tweak that silently flips a planning regime fails loudly:
   so making the transform expensive tips CSR back to DENSE;
 * the rmax fill guard vetoes CSR whenever the padded row format cannot
   hold the rows, regardless of the time comparison;
-* format-aware execution keeps both engine invariants: fused == per-kernel
-  bitwise, and serving (``run_batch``) == naive, with CSR actually taken.
+* format-aware execution keeps the executor's invariants: each kernel's
+  executed format is the planner's decision on its directly profiled
+  operands, the logits match the dense reference, and serving
+  (``run_batch``) == naive bitwise, with CSR actually taken.
 """
 import dataclasses
 
@@ -150,25 +152,38 @@ CHEAP = dataclasses.replace(TPUCostModel(), eff_transform=1.0,
 
 
 def test_fused_matches_per_kernel_with_formats():
-    """Fused executor == per-kernel engine bitwise under format-aware
-    planning, and both engines reach the SAME format decisions."""
-    from repro.core import runtime
+    """Under format-aware planning every kernel executes the format
+    ``analyzer.plan_format`` decides on its directly profiled operands
+    (though the program plans from chained writeback counts), and the
+    logits match the dense float32 reference."""
+    from repro.core import profiler, runtime
     from repro.models import gnn as gnn_models
 
     b = gnn_models.build_dense("sage", "CO", scale=0.05, seed=2)
-    per_kernel = runtime.DynasparseEngine(model=CHEAP, keep_codes=True)
-    fused = runtime.FusedModelExecutor(model=CHEAP, keep_codes=True)
-    env_p, _ = per_kernel.run(b.compiled, b.tensors)
-    env_f, _ = fused.run(b.compiled, b.tensors)
-    last = b.compiled.graph.kernels[-1].out
-    np.testing.assert_array_equal(np.asarray(env_p[last]),
-                                  np.asarray(env_f[last]))
-    assert fused.planned_formats.keys() == per_kernel.planned_formats.keys()
-    for name, f in fused.planned_formats.items():
-        assert int(np.asarray(f)) == per_kernel.planned_formats[name], name
+    fused = runtime.FusedModelExecutor(model=CHEAP, keep_codes=True,
+                                       keep_intermediates=True)
+    env, _ = fused.run(b.compiled, b.tensors)
+    kernels = b.compiled.graph.topo_order()
+    assert fused.planned_formats.keys() == {k.out for k in kernels}
+    for k, (fx, _) in zip(kernels, fused._resolved_flows(b.compiled)):
+        x, y = env[fx.source], env[k.rhs]
+        bm, bk, bn = k.block_dims
+        want = analyzer.plan_format(
+            "dynamic", profiler.block_density(x, (bm, bk)),
+            profiler.block_density(y, (bk, bn)), x.shape, y.shape[1],
+            k.block_dims, CHEAP, kernel_type=k.kernel_type,
+            rmax=fused.csr_rmax)
+        want = Format.DENSE if want is None else int(want)
+        assert int(fused.planned_formats[k.out]) == want, k.out
     # the aggregates of sage actually take the row-CSR path here
     assert any(int(np.asarray(f)) == int(Format.CSR)
                for f in fused.planned_formats.values())
+    spec = gnn_models.make_model_spec(
+        "sage", b.graph.spec.f_in, b.graph.spec.hidden,
+        b.graph.spec.n_classes)
+    ref = gnn_models.reference_forward(spec, b.tensors)
+    _, rel = gnn_models.reference_error(env[kernels[-1].out], ref)
+    assert rel <= 1e-4, rel      # the float32 gate of test_fused_model
 
 
 def test_format_aware_default_engine_is_inert():

@@ -2,20 +2,24 @@
 
 The PR contract for whole-model fusion (DESIGN.md section 9):
 
-* value parity: for EVERY model of the example zoo (GCN / GraphSAGE / GIN /
-  SGC) under EVERY mapping strategy, the fused executor's output is
-  BITWISE equal to the per-kernel engine's -- the dispatch and the
-  density-profile chain never change the numerics;
-* planner parity: the fused path plans each kernel from the producer's
+* kernel parity: for EVERY model of the example zoo under EVERY mapping
+  strategy, each kernel of the whole-model program gives BITWISE the
+  output of the same kernel run alone (``dynasparse_matmul``,
+  ``attention_adjacency``, ``head_combine``) on the program's own
+  materialized operands -- the dispatch and the density-profile chain
+  never change the numerics;
+* planner parity: the program plans each kernel from the producer's
   writeback profile (``out_counts`` pooled by ``BlockProfile.pool_rows``)
-  with NO re-profiling, yet its code grids are identical to the per-kernel
-  path's, which re-profiles every materialized operand -- i.e. the counts
-  chain is exact, not an approximation;
+  with NO re-profiling, yet its code grids and operand densities are
+  identical to those of the kernel run alone, which profiles its
+  materialized operands -- i.e. the counts chain is exact, not an
+  approximation;
 * one jitted call per inference: a full-model run traces once; repeated
   runs re-launch the cached program without re-tracing;
-* report parity: histograms, Alg. 8 makespans, and modeled K2P times agree
-  between the executors, and the fused report additionally models the
-  overlapped (exposed) K2P time of Section V-B2.
+* report parity: histograms, Alg. 8 makespans, and modeled K2P times
+  equal a host recomputation from each row's own densities, and the
+  report additionally models the overlapped (exposed) K2P time of
+  Section V-B2.
 """
 import numpy as np
 import jax
@@ -23,38 +27,73 @@ import jax.numpy as jnp
 import pytest
 
 from repro import hw, spans
-from repro.core import profiler, runtime
+from repro.core import analyzer, profiler, runtime, scheduler
+from repro.core.dynasparse import (attention_adjacency, dynasparse_matmul,
+                                   head_combine)
+from repro.core.ir import KernelType
+from repro.core.perf_model import Primitive
 from repro.models import gnn as gnn_models
 
 STRATEGIES = ("dynamic", "s1", "s2", "gemm")
 
 
-def _run_both(model, strategy, **kw):
+def _run(model, strategy, **kw):
     b = gnn_models.build_dense(model, "CO", scale=0.12, seed=2)
-    per = runtime.DynasparseEngine(strategy=strategy, keep_codes=True, **kw)
-    env_p, rep_p = per.run(b.compiled, b.tensors)
     fused = runtime.FusedModelExecutor(strategy=strategy, keep_codes=True,
                                        **kw)
-    env_f, rep_f = fused.run(b.compiled, b.tensors)
-    return b, (per, env_p, rep_p), (fused, env_f, rep_f)
+    env, rep = fused.run(b.compiled, b.tensors)
+    return b, fused, env, rep
+
+
+def _alone(ex, k, flow, env):
+    """Kernel ``k`` run alone on the materialized operands in ``env``,
+    with the parameters the executor gives it (``flow``: its resolved
+    operand flows, None for a head combination)."""
+    n2 = k.scheme.n2
+    act = k.activation.value if k.activation_enabled else "none"
+    residual = env[k.epilogue_add] if k.epilogue_add is not None else None
+    if k.kernel_type == KernelType.HEAD_COMBINE:
+        return head_combine(tuple(env[h] for h in k.heads_in), residual,
+                            mean=k.head_mean, activation=act,
+                            out_block=(n2, n2))
+    x, y = env[flow[0].source], env[k.rhs]
+    if k.kernel_type == KernelType.ATTENTION:
+        return attention_adjacency(
+            x, y, env[k.att_src], env[k.att_dst], slope=k.att_slope,
+            threshold=k.att_threshold, out_block=(n2, n2))
+    return dynasparse_matmul(
+        x, y, residual=residual, strategy=ex.strategy,
+        kernel_type=k.kernel_type,
+        epilogue_scale=k.epilogue_scale if residual is not None else 1.0,
+        activation=act, out_block=(n2, n2), block=k.block_dims,
+        cost_model=ex.model, format_aware=ex.format_aware,
+        csr_rmax=ex.csr_rmax)
 
 
 @pytest.mark.parametrize("model", gnn_models.GNN_MODELS)
 def test_fused_matches_per_kernel_bitwise(model):
-    """All four strategies: bitwise-equal outputs AND identical planner
-    code sequences, though the fused path never re-profiles an
-    intermediate (it plans from the chained writeback counts)."""
+    """All four strategies: every kernel of the program is bitwise the
+    kernel run alone on the program's materialized operands, with
+    identical planner codes and operand densities, though the program
+    never re-profiles an intermediate (it plans from the chained
+    writeback counts)."""
     for strategy in STRATEGIES:
-        b, (per, env_p, _), (fused, env_f, _) = _run_both(model, strategy)
-        last = b.compiled.graph.kernels[-1].out
-        np.testing.assert_array_equal(
-            np.asarray(env_p[last]), np.asarray(env_f[last]),
-            err_msg=f"{model}/{strategy}: outputs differ")
-        assert per.planned_codes.keys() == fused.planned_codes.keys()
-        for out, codes in per.planned_codes.items():
+        b, fused, env, rep = _run(model, strategy, keep_intermediates=True)
+        flows = fused._resolved_flows(b.compiled)
+        kernels = b.compiled.graph.topo_order()
+        for k, flow, row in zip(kernels, flows, rep.kernels):
+            res = _alone(fused, k, flow, env)
+            where = f"{model}/{strategy}/{k.out}"
             np.testing.assert_array_equal(
-                codes, fused.planned_codes[out],
-                err_msg=f"{model}/{strategy}/{out}: planner codes differ")
+                np.asarray(res.out), np.asarray(env[k.out]),
+                err_msg=f"{where}: outputs differ")
+            np.testing.assert_array_equal(
+                np.asarray(res.codes), fused.planned_codes[k.out],
+                err_msg=f"{where}: planner codes differ")
+            np.testing.assert_array_equal(np.asarray(res.dens_x), row.dens_x,
+                                          err_msg=where)
+            np.testing.assert_array_equal(np.asarray(res.dens_y), row.dens_y,
+                                          err_msg=where)
 
 
 # reference gate, as a fraction of max |reference| (float32 on the CPU)
@@ -82,29 +121,51 @@ def test_fused_matches_float32_reference(model):
     assert rel_no_agg > 100 * REF_TOL, (model, rel_no_agg)
 
 
+# the codes of the kernels that plan nothing: attention is one dense task,
+# the head combination one skipped step (``core.dynasparse``)
+_FIXED = {KernelType.ATTENTION: Primitive.GEMM,
+          KernelType.HEAD_COMBINE: Primitive.SKIP}
+
+
 @pytest.mark.parametrize("strategy", STRATEGIES)
 @pytest.mark.parametrize("model", ["gcn", "gat"])
 def test_fused_report_matches_per_kernel(model, strategy):
-    """The fused report -- histogram counted on the device, rows built
-    on first read from the packed buffers -- equals the per-kernel
-    engine's, field by field."""
-    _, (_, _, rep_p), (_, _, rep_f) = _run_both(model, strategy)
-    np.testing.assert_array_equal(rep_p.histogram, rep_f.histogram)
-    assert rep_f.histogram.dtype == rep_p.histogram.dtype
-    assert len(rep_p.kernels) == len(rep_f.kernels) == len(rep_f.counts)
-    for kp, kf, counts in zip(rep_p.kernels, rep_f.kernels, rep_f.counts):
-        np.testing.assert_array_equal(kp.histogram, kf.histogram)
-        np.testing.assert_array_equal(kp.histogram, counts)
-        assert kp.name == kf.name and kp.num_tasks == kf.num_tasks
-        assert kp.makespan_cycles == kf.makespan_cycles
-        assert kp.utilization == kf.utilization
-        assert kp.k2p_seconds == kf.k2p_seconds
-        np.testing.assert_array_equal(kp.dens_x, kf.dens_x)
-        np.testing.assert_array_equal(kp.dens_y, kf.dens_y)
-    assert rep_p.total_cycles == rep_f.total_cycles
-    assert rep_p.k2p_seconds == rep_f.k2p_seconds
-    assert rep_f.fused_wall_seconds is not None
-    assert rep_f.wall_seconds == rep_f.fused_wall_seconds > 0.0
+    """The report -- histogram counted on the device, rows built on first
+    read from the packed buffers -- equals, row by row, the histogram,
+    Alg. 8 makespan and modeled K2P time recomputed on the host from the
+    row's own densities."""
+    b, fused, _, rep = _run(model, strategy)
+    kernels = b.compiled.graph.topo_order()
+    n_cc = b.compiled.partition.n_cc
+    assert len(rep.kernels) == len(rep.counts) == len(kernels)
+    makespans = []
+    for k, row, counts in zip(kernels, rep.kernels, rep.counts):
+        if k.kernel_type in _FIXED:
+            codes = np.full((1, 1, 1), int(_FIXED[k.kernel_type]), np.int32)
+            costs = analyzer.task_costs_host(codes, row.dens_x, row.dens_y,
+                                             k.block_dims, fused.model)
+        else:
+            codes, costs = analyzer.plan_kernel_host(
+                strategy, row.dens_x, row.dens_y, k.block_dims, fused.model,
+                kernel_type=k.kernel_type)
+        np.testing.assert_array_equal(codes, fused.planned_codes[k.out])
+        hist = np.bincount(codes.reshape(-1), minlength=4)
+        np.testing.assert_array_equal(row.histogram, hist)
+        np.testing.assert_array_equal(row.histogram, counts)
+        assert row.name == k.name and row.num_tasks == costs.size
+        sched = scheduler.schedule_dynamic(costs.reshape(-1), n_cc)
+        assert row.makespan_cycles == sched.makespan
+        assert row.utilization == sched.utilization
+        assert row.k2p_seconds == (codes.size * runtime._K2P_INSTRUCTIONS
+                                   / runtime._SOFT_PROC_IPS)
+        makespans.append(sched.makespan)
+    np.testing.assert_array_equal(rep.histogram,
+                                  np.sum([r.histogram for r in rep.kernels],
+                                         axis=0))
+    assert rep.histogram.dtype == np.int64
+    assert rep.total_cycles == float(sum(makespans))
+    assert rep.fused_wall_seconds is not None
+    assert rep.wall_seconds == rep.fused_wall_seconds > 0.0
 
 
 def _profile(tmp_path):
@@ -235,7 +296,7 @@ def test_operand_flows_wiring():
 def test_k2p_overlap_model():
     """Exposed (overlapped) K2P time: bounded by the serial sum, and no
     lower than the first kernel's un-hideable planning time."""
-    _, (_, _, _), (_, _, rep) = _run_both("gcn", "dynamic")
+    _, _, _, rep = _run("gcn", "dynamic")
     freq = hw.ALVEO_U250.freq_hz
     exposed = rep.k2p_exposed_seconds(freq)
     assert 0.0 < exposed <= rep.k2p_seconds

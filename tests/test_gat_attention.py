@@ -3,7 +3,7 @@ density the planner exploits (DESIGN.md §17).
 
 What this file pins beyond the model sweeps (``test_fused_model`` /
 ``test_graph_serving`` parametrize over ``GNN_MODELS`` and already cover
-GAT's fused-vs-per-kernel and serving-vs-oracle bitwise parity):
+GAT's kernel-alone and serving-vs-oracle bitwise parity):
 
 * ``attention_adjacency`` semantics: masked softmax restricted to the
   adjacency support, rows sum to 1 pre-threshold, all-zero rows (bucket
@@ -131,7 +131,8 @@ def test_attention_sparsity_drives_the_plan():
     nnz = {}
     for threshold in (0.0, 0.6):
         cm, tensors = _gat_bundle(threshold=threshold, heads=1)
-        eng = runtime.DynasparseEngine(keep_codes=True)
+        eng = runtime.FusedModelExecutor(keep_codes=True,
+                                         keep_intermediates=True)
         env, _ = eng.run(cm, tensors)
         codes[threshold] = eng.planned_codes["G1h1"]  # head 1's aggregate
         nnz[threshold] = int(np.asarray(env["T1h1"]).astype(bool).sum())
@@ -217,15 +218,12 @@ def test_published_gat_ir_combines_heads_once_per_layer():
 
 @pytest.mark.parametrize("threshold", [0.0, 0.05])
 def test_published_gat_engines_match_the_reference(threshold):
-    """Both engines run the published equations: bitwise equal to each
-    other and within the float32 gate of ``reference_forward``, at the
-    exact softmax and with a threshold that drops weights."""
+    """The executor runs the published equations: within the float32 gate
+    of ``reference_forward``, at the exact softmax and with a threshold
+    that drops weights."""
     spec, cm, tensors = _published(threshold)
-    env_p, _ = runtime.DynasparseEngine().run(cm, tensors)
     fused = runtime.FusedModelExecutor(keep_intermediates=True)
     env_f, _ = fused.run(cm, tensors)
-    np.testing.assert_array_equal(np.asarray(env_p["H3"]),
-                                  np.asarray(env_f["H3"]))
     ref = gnn_models.reference_forward(spec, tensors)
     assert env_f["H3"].shape == ref.shape == (150, 5)
     _, rel = gnn_models.reference_error(env_f["H3"], ref)

@@ -4,8 +4,9 @@ The serving contract (DESIGN.md section 10):
 
 * bitwise parity: for EVERY model of the zoo, ``GraphServeEngine.serve``
   returns per-request outputs bitwise equal to the naive per-request
-  ``DynasparseEngine.run`` on the same padded tensors -- wave batching,
-  the scan, and dummy slot padding never touch a request's numerics;
+  single-inference ``FusedModelExecutor.run`` on the same padded tensors
+  -- wave batching, the scan, and dummy slot padding never touch a
+  request's numerics;
 * one jit trace per shape bucket: waves are padded to a fixed slot count,
   so repeated serving across any request mix re-traces only when a NEW
   bucket appears;
@@ -36,7 +37,7 @@ def _reqs(n=5, seed=1, sizes=(24, 60)):
 
 @pytest.mark.parametrize("model", gnn_models.GNN_MODELS)
 def test_serve_matches_per_request_bitwise(model):
-    """Whole zoo: served outputs == naive per-request engine outputs, bit
+    """Whole zoo: served outputs == naive single-inference outputs, bit
     for bit, across mixed-size requests spanning two buckets (so waves mix
     real and dummy slots)."""
     eng = _engine(model)
@@ -233,9 +234,9 @@ def test_run_batch_report_modes():
     assert rep.kernels[0].name.endswith("[0]")
     assert rep.kernels[-1].name.endswith(f"[{len(reqs) - 1}]")
     assert rep.fused_wall_seconds > 0.0
-    # per-request parity of the planned codes vs the per-kernel engine
-    per = runtime.DynasparseEngine(strategy="dynamic", n_cc=full.n_cc,
-                                   keep_codes=True)
+    # per-request parity of the planned codes vs a single inference
+    per = runtime.FusedModelExecutor(strategy="dynamic", n_cc=full.n_cc,
+                                     keep_codes=True)
     tensors = dict(full.weights)
     bucket = full.buckets[0]
     tensors.update({k: v for k, v in full._padded(reqs[0], bucket).items()
@@ -245,3 +246,17 @@ def test_run_batch_report_modes():
         np.testing.assert_array_equal(
             codes, full.executor.planned_codes[out][0],
             err_msg=f"{out}: slot-0 planner codes differ from per-request")
+
+
+def test_run_naive_leaves_the_shared_weights_to_the_waves():
+    """``run_naive`` never donates its inputs, which include the weights
+    every wave reads: serving the same requests after it still reads
+    them, and its results are bitwise the naive ones."""
+    eng = _engine("gcn", donate=True)
+    reqs = _reqs(4)
+    naive = eng.run_naive(reqs)
+    served = eng.serve(reqs)
+    assert all(not w.is_deleted() for w in eng.weights.values())
+    for s, n in zip(served, naive):
+        np.testing.assert_array_equal(s.logits, n.logits,
+                                      err_msg=f"request {s.request_id}")

@@ -3,9 +3,9 @@
 
 The load-bearing invariant: mini-batch serving -- sampler, pinned store
 gather, shape-bucketed waves, hot-vertex cache, coalescing -- is BITWISE
-equal to the per-seed ``run_naive`` oracle (one ``DynasparseEngine.run``
-per sampled subgraph), across all four models, arrival orders, and cache
-states.  Staleness: after a feature-store update no served result may
+equal to the per-seed ``run_naive`` oracle (one single-inference
+``FusedModelExecutor.run`` per sampled subgraph), across all four models,
+arrival orders, and cache states.  Staleness: after a feature-store update no served result may
 reflect pre-update features, and cache accounting must conserve.
 """
 import functools

@@ -2,14 +2,14 @@
 
 The PR contract for the plan/execute split (DESIGN.md section 1):
 
-* value preservation: for every strategy the fused engine's output equals
+* value preservation: for every strategy the executor's output equals
   the dense oracle (``dynasparse_dense_equivalent`` applied kernel by
   kernel, epilogues included) to fp32 tolerance;
-* planner parity: the histogram the engine reports (derived from the
-  traced planner's codes) matches what the host-side cost-model planner
-  (``analyzer.plan_kernel_host`` -- the simulator's path) produces on the
-  same profiled densities;
-* one traced call per kernel: repeated shapes hit the executable cache;
+* planner parity: the histogram the executor reports (counted on the
+  device from the traced planner's codes) matches what the host-side
+  cost-model planner (``analyzer.plan_kernel_host`` -- the simulator's
+  path) produces on the same profiled densities;
+* one traced program per model: repeated shapes hit the program cache;
 * K2P time: both the modeled soft-processor time and the measured host
   wall time are reported (the seed's ``* 0.0`` dead code is gone).
 """
@@ -52,7 +52,7 @@ def _dense_reference(compiled, tensors):
 @pytest.mark.parametrize("strategy", STRATEGIES)
 def test_engine_matches_dense_equivalent(model, strategy):
     b = gnn_models.build_dense(model, "CO", scale=0.12, seed=2)
-    out, rep = b.run(runtime.DynasparseEngine(strategy=strategy))
+    out, rep = b.run(runtime.FusedModelExecutor(strategy=strategy))
     want = _dense_reference(b.compiled, b.tensors)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-3, rtol=2e-3)
@@ -64,14 +64,17 @@ def test_engine_histogram_matches_host_planner(strategy):
     """Traced planner (inside the executor) == host planner (simulator path)
     on the same profiled densities, per kernel."""
     b = gnn_models.build_dense("gcn", "CO", scale=0.12, seed=2)
-    eng = runtime.DynasparseEngine(strategy=strategy)
+    eng = runtime.FusedModelExecutor(strategy=strategy)
     _, rep = b.run(eng)
-    for k, krep in zip(b.compiled.graph.topo_order(), rep.kernels):
+    kernels = b.compiled.graph.topo_order()
+    assert len(rep.kernels) == len(rep.counts) == len(kernels)
+    for k, krep, counts in zip(kernels, rep.kernels, rep.counts):
         codes, _ = analyzer.plan_kernel_host(
             strategy, krep.dens_x, krep.dens_y, k.block_dims, eng.model,
             kernel_type=k.kernel_type)
         hist = np.bincount(codes.reshape(-1), minlength=4)
         np.testing.assert_array_equal(hist, krep.histogram, err_msg=k.name)
+        np.testing.assert_array_equal(hist, counts, err_msg=k.name)
 
 
 @pytest.mark.parametrize("strategy", STRATEGIES)
@@ -126,18 +129,18 @@ def test_precomputed_codes_override_planner():
 
 def test_executor_cache_hits_on_repeated_shapes():
     b = gnn_models.build_dense("gcn", "CO", scale=0.12, seed=2)
-    eng = runtime.DynasparseEngine()
+    eng = runtime.FusedModelExecutor()
     b.run(eng)
-    first_misses = eng.cache_misses
-    assert first_misses == len(b.compiled.graph.kernels)
-    b.run(eng)   # same shapes: every kernel re-launches a cached executable
-    assert eng.cache_misses == first_misses
-    assert eng.cache_hits >= len(b.compiled.graph.kernels)
+    assert eng.cache_misses == 1 and eng.cache_hits == 0
+    b.run(eng)   # same shapes: the cached program re-launches
+    b.run(eng)
+    assert eng.cache_misses == 1 and eng.cache_hits == 2
+    assert eng.trace_count == 1
 
 
 def test_k2p_reports_modeled_and_measured():
     b = gnn_models.build_dense("gcn", "CO", scale=0.12, seed=2)
-    _, rep = b.run(runtime.DynasparseEngine())
+    _, rep = b.run(runtime.FusedModelExecutor())
     for krep in rep.kernels:
         # modeled soft-processor time: linear in the decision count
         want = (krep.histogram.sum() * runtime._K2P_INSTRUCTIONS
@@ -145,8 +148,3 @@ def test_k2p_reports_modeled_and_measured():
         assert krep.k2p_seconds == pytest.approx(want)
         # measured host wall time is reported, not multiplied away
         assert krep.k2p_wall_seconds > 0.0
-
-
-def test_engine_has_no_per_block_dispatch_loop():
-    """The seed's Python triple loop is gone: one traced call per kernel."""
-    assert not hasattr(runtime.DynasparseEngine, "_blocked_matmul")

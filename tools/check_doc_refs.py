@@ -30,10 +30,9 @@ _MOD_RE = re.compile(r"`(repro(?:\.\w+)+)`")
 # the public surface the documentation's prose names without backticked
 # dotted paths; keep in sync with README "Choosing an executor" / DESIGN 0/9
 PUBLIC = [
-    ("repro.core.runtime", ["DynasparseEngine", "FusedModelExecutor",
-                            "simulate_inference", "propagate_stats",
-                            "InferenceReport"]),
-    # attention_adjacency is the GAT edge-softmax both engines execute
+    ("repro.core.runtime", ["FusedModelExecutor", "simulate_inference",
+                            "propagate_stats", "InferenceReport"]),
+    # attention_adjacency is the GAT edge-softmax the executor runs
     # (DESIGN 17 / README "Serving a mutating graph")
     ("repro.core.dynasparse", ["dynasparse_matmul", "DynasparseResult",
                                "dynasparse_dense_equivalent",
